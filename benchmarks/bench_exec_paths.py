@@ -224,13 +224,14 @@ def bench_sharded_scaling(mesh_sizes=(1, 2, 4)):
     """Sharded paged-KV serving scaling: the same mixed shared-prefix
     queue served with the page pool split over 1/2/4 devices.
 
-    Each mesh size runs in a subprocess with that many forced host
-    devices (XLA_FLAGS must precede jax init, the test_distributed.py
-    idiom).  Interpret-mode CPU wall time measures dispatch + collective
-    overhead, not TPU performance — the committed baseline pins the
-    schema and the cross-topology invariant: every mesh size emits
-    token-identical streams and reports its per-device page budget and
-    occupancy."""
+    Each mesh size runs in a subprocess pinned to the CPU with that many
+    forced host devices (XLA_FLAGS must precede jax init, the
+    test_distributed.py idiom; JAX_PLATFORMS=cpu keeps the children off
+    an accelerator this process may already hold).  Interpret-mode CPU
+    wall time measures dispatch + collective overhead, not TPU
+    performance — the committed baseline pins the schema and the
+    cross-topology invariant: every mesh size emits token-identical
+    streams and reports its per-device page budget and occupancy."""
     import json
     import os
     import subprocess
@@ -283,7 +284,7 @@ def bench_sharded_scaling(mesh_sizes=(1, 2, 4)):
     """)
     rows = []
     for n in mesh_sizes:
-        env = {**os.environ,
+        env = {**os.environ, "JAX_PLATFORMS": "cpu",
                "XLA_FLAGS": f"--xla_force_host_platform_device_count={n}",
                "PYTHONPATH": os.path.join(repo, "src")}
         r = subprocess.run([sys.executable, "-c", code.format(n=n)],

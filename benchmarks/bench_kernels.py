@@ -39,8 +39,10 @@ except ImportError:  # bare-script run: benchmarks/ itself is sys.path[0]
 from repro.core import posit
 from repro.core.formats import P13_2, P16_1, P16_2, P8_2, PDPUConfig
 from repro.kernels import autotune, ops
-from repro.launch.mesh import HW
+from repro.launch.mesh import PEAKS, V5E
 from repro.models import common, paged
+
+HW = PEAKS[V5E]  # the roofline columns model one TPU v5e
 
 
 def _time(fn, *args, reps=3):

@@ -17,6 +17,7 @@ from repro import configs
 from repro.core import posit
 from repro.core.formats import P8_2, P10_2, P13_2, P16_2
 from repro.data import DataConfig, Pipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.models.config import ShapeConfig
 from repro.models.module import param_count
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--arch", default="minitron_8b")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get_smoke(args.arch)
     shape = ShapeConfig("ptq", seq_len=128, global_batch=8, kind="train")

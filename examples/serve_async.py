@@ -31,6 +31,7 @@ import numpy as np
 from repro import configs
 from repro.core.formats import P8_2, P16_2
 from repro.core.quant import QuantPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.serve import (AsyncServingFrontend, Request, ServingEngine,
                          SLOClass)
@@ -39,6 +40,7 @@ N_REQ = int(os.environ.get("SERVE_ASYNC_REQUESTS", "4"))
 MAX_NEW = int(os.environ.get("SERVE_ASYNC_TOKENS", "8"))
 SPEC_K = 4
 
+enable_compile_cache()
 cfg = configs.get_tiny_serving(
     "command_r_35b", QuantPolicy(weights=P16_2, kv_cache=P8_2))
 params = api.init(jax.random.key(0), cfg)

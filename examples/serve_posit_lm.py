@@ -1,7 +1,7 @@
 """Serving with posit-packed weights + a paged posit-KV cache.
 
 End-to-end demonstration of the paged serving runtime:
-  1. a float checkpoint's qdot weights are packed once to P(16,2) codes
+  1. the qdot weights are built from a seed straight into P(16,2) codes
      (int16 — half the bf16 bytes, quarter the f32 bytes),
   2. the packed tree is checkpointed with pack metadata in the manifest,
   3. `ServingEngine.from_checkpoint` restores the codes and serves them
@@ -32,19 +32,22 @@ from repro import configs
 from repro.checkpoint import CheckpointManager
 from repro.core.quant import policy_by_name
 from repro.kernels import autotune
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
+from repro.models.module import param_bytes
 from repro.serve import Request, ServingEngine
 
 N_REQ = int(os.environ.get("SERVE_DEMO_REQUESTS", "10"))
 MAX_NEW = int(os.environ.get("SERVE_DEMO_TOKENS", "12"))
 
+enable_compile_cache()
 cfg = configs.get_smoke("command_r_35b").replace(
     quant=policy_by_name("serve_fused_p16"))
-params = api.init(jax.random.key(0), cfg)
 
-# one-shot pack pass: float masters -> posit code arrays (int16)
-packed = api.pack_params(params, cfg)
-f32_bytes = api.weight_bytes(params)
+# weights built from the seed straight into posit code arrays (int16), one
+# leaf at a time — the float tree never exists
+packed = api.init_packed(jax.random.key(0), cfg)
+f32_bytes = param_bytes(api.param_specs(cfg))
 packed_bytes = api.weight_bytes(packed)
 print(f"weights: {f32_bytes} B float -> {packed_bytes} B packed "
       f"({f32_bytes / packed_bytes:.2f}x smaller)")

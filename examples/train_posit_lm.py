@@ -21,6 +21,7 @@ import jax
 from repro import configs
 from repro.core.quant import TRAINABLE_PLANS, policy_by_name
 from repro.data import DataConfig, Pipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.config import ShapeConfig
 from repro.optim import adamw, cosine_schedule
 from repro.train import Trainer, TrainerConfig
@@ -50,6 +51,7 @@ def main():
                     help="QAT datapath: fake_quant (STE on float dots) or "
                          "fused (packed Pallas kernel forward, STE backward)")
     args = ap.parse_args()
+    enable_compile_cache()
     base = run("none", args.steps, args.arch)
     mixed = run("paper_mixed", args.steps, args.arch, args.execution)
     n = max(args.steps // 5, 1)

@@ -59,9 +59,10 @@ Cost oracle
 The sweep is pruned before any timing: `oracle_cost(kernel, shape, params)`
 computes the *padded* FLOP and HBM-byte volume a candidate tiling actually
 launches (tiles larger than a dim pad it up — real wasted work) and turns
-them into a roofline time with the `launch.mesh.HW` constants, exactly the
-model `benchmarks/roofline.py` applies to the dryrun sweeps.  Candidates
-whose oracle time exceeds `prune_factor` x the best oracle time are never
+them into a roofline time with the published peaks of the chip the
+kernels run on (`device_peaks`), the model `benchmarks/roofline.py`
+applies to the dryrun sweeps.
+Candidates whose oracle time exceeds `prune_factor` x the best oracle time are never
 timed.  `hlo_cost(fn, *args)` lowers + compiles a candidate and runs
 `launch.hlo_analysis.analyze_hlo` over the HLO text — the CLI's
 `--oracle-check` mode uses it to validate the analytic model against the
@@ -167,12 +168,25 @@ def _pad_up(d, b):
     return -(-d // b) * b
 
 
+def device_peaks() -> dict:
+    """Published peaks of the chip the kernels run on, looked up by the
+    attached TPU's device kind (an unknown kind raises).  In interpret
+    mode there is no such chip: the kernels stand in for the TPU v5e they
+    are written for."""
+    from repro.kernels import ops
+    from repro.launch.mesh import PEAKS, V5E, peaks
+
+    if ops.interpret_mode():
+        return PEAKS[V5E]
+    return peaks(jax.devices()[0].device_kind)
+
+
 def oracle_cost(kernel: str, shape, params: dict, fmts=()) -> float:
     """Roofline seconds for one launch of `kernel` at `shape` under a
-    candidate tiling: padded FLOPs / padded HBM bytes through the
-    `launch.mesh.HW` constants.  Used to prune the sweep — a tile that pads
-    a dim 4x does 4x the work, which the oracle sees without timing it."""
-    from repro.launch.mesh import HW
+    candidate tiling: padded FLOPs / padded HBM bytes through the chip's
+    peaks (`device_peaks`).  Used to prune the sweep — a tile that pads a
+    dim 4x does 4x the work, which the oracle sees without timing it."""
+    hw = device_peaks()
 
     def elt_bytes(i):
         f = fmts[i] if i < len(fmts) else None
@@ -230,7 +244,7 @@ def oracle_cost(kernel: str, shape, params: dict, fmts=()) -> float:
         flops = 2.0 * B * D * vp + 8.0 * B * vp
     else:
         raise KeyError(f"no oracle for kernel '{kernel}'")
-    return max(flops / HW["peak_flops_bf16"], bytes_ / HW["hbm_bw"])
+    return max(flops / hw["peak_flops_bf16"], bytes_ / hw["hbm_bw"])
 
 
 def hlo_cost(fn, *args) -> dict:

@@ -32,7 +32,9 @@ from . import prefill_attention as prefill_attention_mod
 from . import ref  # noqa: F401  (re-exported for tests/benchmarks)
 
 
-def _interpret() -> bool:
+def interpret_mode() -> bool:
+    """The one decision whether Pallas kernels run in interpret mode: off
+    a TPU backend they do (the kernel body executes as jnp on the host)."""
     return jax.default_backend() != "tpu"
 
 
@@ -100,14 +102,14 @@ def decode(codes, fmt: PositFormat, **kw):
     """posit codes -> f32 (Pallas elementwise kernel)."""
     kw = _resolve("posit_codec.decode", _flat2d(codes.shape), (fmt,), kw,
                   ("block_r", "block_c"))
-    return posit_codec.decode(codes, fmt, interpret=_interpret(), **kw)
+    return posit_codec.decode(codes, fmt, interpret=interpret_mode(), **kw)
 
 
 def encode(values, fmt: PositFormat, **kw):
     """float -> posit codes in storage dtype (Pallas elementwise kernel)."""
     kw = _resolve("posit_codec.encode", _flat2d(values.shape), (fmt,), kw,
                   ("block_r", "block_c"))
-    return posit_codec.encode(values, fmt, interpret=_interpret(), **kw)
+    return posit_codec.encode(values, fmt, interpret=interpret_mode(), **kw)
 
 
 def fused_matmul(a_codes, b_codes, fmt_a: PositFormat, fmt_b: PositFormat,
@@ -118,7 +120,7 @@ def fused_matmul(a_codes, b_codes, fmt_a: PositFormat, fmt_b: PositFormat,
                   (fmt_a, fmt_b), kw, ("bm", "bn", "bk"))
     return posit_matmul.posit_matmul(
         a_codes, b_codes, fmt_a, fmt_b, fmt_out,
-        interpret=_interpret(), **kw)
+        interpret=interpret_mode(), **kw)
 
 
 def fused_matmul_grouped(a_codes, b_codes, fmt_a: PositFormat,
@@ -134,7 +136,7 @@ def fused_matmul_grouped(a_codes, b_codes, fmt_a: PositFormat,
                   (fmt_a, fmt_b), kw, ("bm", "bn", "bk"))
     return posit_matmul.posit_matmul_grouped(
         a_codes, b_codes, fmt_a, fmt_b, fmt_out,
-        interpret=_interpret(), **kw)
+        interpret=interpret_mode(), **kw)
 
 
 def matmul_posit_weights_grouped(x, w_codes, fmt_w: PositFormat, **kw):
@@ -150,7 +152,7 @@ def matmul_posit_weights_grouped(x, w_codes, fmt_w: PositFormat, **kw):
                   (None, fmt_w), kw, ("bm", "bn", "bk"))
     return posit_matmul.posit_matmul_grouped(
         x.astype(jnp.float32), w_codes, None, fmt_w, None,
-        interpret=_interpret(), **kw)
+        interpret=interpret_mode(), **kw)
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths, window,
@@ -182,7 +184,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, window,
         t_block = _degrade_tile(q.shape[1], kw.get("t_block"))
     return paged_attention_mod.paged_attention(
         q, k_pages, v_pages, block_tables, lengths, window,
-        fmt_kv=fmt_kv, softcap_val=softcap_val, interpret=_interpret(),
+        fmt_kv=fmt_kv, softcap_val=softcap_val, interpret=interpret_mode(),
         page_ok=page_ok, partials=partials, t_block=t_block)
 
 
@@ -208,7 +210,7 @@ def prefill_attention_paged(q, k, v, k_pages, v_pages, block_tables, starts,
     return prefill_attention_mod.prefill_attention_paged(
         q, k, v, k_pages, v_pages, block_tables, starts, window,
         fmt_kv=fmt_kv, compute_dtype=compute_dtype, softcap_val=softcap_val,
-        flash_chunk=flash_chunk, interpret=_interpret(),
+        flash_chunk=flash_chunk, interpret=interpret_mode(),
         hist_pool_k=hist_pool_k, hist_pool_v=hist_pool_v, hist_bt=hist_bt,
         page_ok=page_ok, **kw)
 
@@ -223,22 +225,21 @@ def decode_sample(x, w, noise=None, temperature=None, *, plan: str = "fused",
     (kernels/paged_attention.py:decode_sample) so a decode step's logits
     never round-trip through HBM — bit-identical to running `logits_head`
     and the engine sampler as separate device programs.  `v_block` resolves
-    through the autotune cache (0 = whole vocab / collapsed grid); a cached
-    tile that doesn't divide this vocab degrades to the largest divisor
-    below it, like `paged_attention`'s t_block, and is dropped in favour of
-    the untuned whole-vocab default when that collapses below the tile
-    floor (prime vocab)."""
+    through the autotune cache (0 = whole vocab / collapsed grid); a tile
+    that doesn't divide this vocab runs with a masked partial last tile,
+    and a miss takes the kernel's untuned, VMEM-budgeted default
+    (`paged_attention.default_v_block`)."""
     V = w.shape[0] if transpose else w.shape[1]
     if v_block is None:
         kw = _resolve("decode_sample", (x.shape[0], x.shape[1], V),
                       (fmt_w,), {}, ("v_block",))
         vb = kw.get("v_block")
         if vb is not None:
-            v_block = V if vb == 0 else _degrade_tile(V, vb)
+            v_block = V if vb == 0 else vb
     return paged_attention_mod.decode_sample(
         x, w, noise, temperature, plan=plan, fmt_w=fmt_w,
         transpose=transpose, greedy=greedy, top_k=top_k,
-        softcap_val=softcap_val, v_block=v_block, interpret=_interpret())
+        softcap_val=softcap_val, v_block=v_block, interpret=interpret_mode())
 
 
 def merge_attn_partials(o, m, l, axis_name: str):
@@ -262,20 +263,22 @@ def merge_attn_partials(o, m, l, axis_name: str):
 def pdpu_matmul(a_codes, b_codes, cfg: PDPUConfig, **kw):
     """Bit-exact chunked-PDPU GEMM (hardware-faithful W_m datapath)."""
     return pdpu_dot.pdpu_matmul(a_codes, b_codes, cfg,
-                                interpret=_interpret(), **kw)
+                                interpret=interpret_mode(), **kw)
 
 
 def matmul_posit_weights(x, w_codes, fmt_w: PositFormat, **kw):
     """float activations x posit-stored weights — the serving fast path.
 
     Activations stay float (encoding them would add a rounding); the posit
-    weights decode exactly in-kernel and the dot accumulates f32.  Returns
-    f32.  (Used by the dispatch layer for posit-weight checkpoints when
-    QuantPolicy.activations is None.)
+    weights decode exactly in-kernel and the dot takes both operands at f32
+    precision (the MXU default would round them to bf16) and accumulates
+    f32.  Returns f32.  (Used by the dispatch layer for posit-weight
+    checkpoints when QuantPolicy.activations is None.)
     """
     a = x.astype(jnp.float32)
-    w = posit_codec.decode(w_codes, fmt_w, interpret=_interpret(), **kw)
-    return jnp.dot(a, w, preferred_element_type=jnp.float32)
+    w = posit_codec.decode(w_codes, fmt_w, interpret=interpret_mode(), **kw)
+    return jnp.dot(a, w, precision=posit_matmul.F32,
+                   preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -46,10 +46,6 @@ from repro.core.formats import PositFormat
 
 _NEG = -2.0e38
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-
 def _softcap(x, cap: float):
     if cap <= 0:
         return x
@@ -58,14 +54,25 @@ def _softcap(x, cap: float):
 
 def _paged_attention_kernel(bt_ref, len_ref, win_ref, ok_ref, q_ref, k_ref,
                             v_ref, *refs, fmt_kv: PositFormat | None,
-                            page_size: int, n_heads: int, n_kv_heads: int,
-                            head_dim: int, softcap_val: float, partials: bool):
+                            page_size: int, t_total: int, t_block: int,
+                            n_heads: int, n_kv_heads: int, head_dim: int,
+                            softcap_val: float, partials: bool):
+    """One (slot, query tile, page) grid cell: T new tokens per slot.
+
+    Query row i of slot b sits at absolute position lengths[b] - T + i
+    (all T tokens already inserted); causality between the new tokens is
+    enforced by the same position mask that guards written-prefix reads.
+    Rows are independent and each runs the same one-token streaming step,
+    so any t_block tiling of T is bitwise identical — t_block is the
+    autotuned launch parameter.  The one-token decode is the T=1 case.
+    """
     if partials:
         out_ref, m_ref, l_ref, m_scr, l_scr, o_scr = refs
     else:
         (out_ref, m_scr, l_scr, o_scr), m_ref, l_ref = refs, None, None
     b = pl.program_id(0)
-    p = pl.program_id(1)
+    t = pl.program_id(1)
+    p = pl.program_id(2)
     G = n_heads // n_kv_heads
 
     @pl.when(p == 0)
@@ -86,176 +93,44 @@ def _paged_attention_kernel(bt_ref, len_ref, win_ref, ok_ref, q_ref, k_ref,
     v = v.reshape(page_size, n_kv_heads, head_dim)
 
     scale = 1.0 / math.sqrt(head_dim)
-    qg = q_ref[0].reshape(n_kv_heads, G, head_dim).astype(jnp.float32) * scale
-    s = jnp.einsum("hgd,khd->hgk", qg, k)  # [Hkv, G, ps]
-    s = _softcap(s, softcap_val)
-
-    length = len_ref[b]
     pos = p * page_size + jax.lax.iota(jnp.int32, page_size)
-    q_pos = length - 1  # the query token sits at the last written position
-    mask = (pos < length) & ((q_pos - pos) < win_ref[0]) & (ok_ref[b, p] > 0)
-    s = jnp.where(mask[None, None, :], s, _NEG)
+    page_ok = ok_ref[b, p] > 0
+    # one query row at a time: Mosaic cannot split the fused [T, heads]
+    # axis of a multi-row einsum into (kv head, group)
+    for i in range(t_block):
+        q_pos = len_ref[b] - t_total + t * t_block + i
+        mask = (pos <= q_pos) & ((q_pos - pos) < win_ref[0]) & page_ok
+        qg = q_ref[0, i].reshape(n_kv_heads, G, head_dim) \
+                        .astype(jnp.float32) * scale
+        s = jnp.einsum("hgd,khd->hgk", qg, k)  # [Hkv, G, ps]
+        s = _softcap(s, softcap_val)
+        s = jnp.where(mask[None, None, :], s, _NEG)
 
-    m_prev, l_prev, o_prev = m_scr[...], l_scr[...], o_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    pr = jnp.exp(s - m_new[..., None])
-    pr = jnp.where(mask[None, None, :], pr, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    m_scr[...] = m_new
-    l_scr[...] = l_prev * corr + jnp.sum(pr, axis=-1)
-    o_scr[...] = o_prev * corr[..., None] + jnp.einsum("hgk,khd->hgd", pr, v)
-
-    @pl.when(p == pl.num_programs(1) - 1)
-    def _finalize():
-        if partials:
-            # leave the streaming state unnormalized: (o, m, l) per slot, to
-            # be log-sum-exp merged across kv_pages shards (ops.
-            # merge_attn_partials) before the single final normalization
-            out_ref[0] = o_scr[...].reshape(n_heads, head_dim)
-            m_ref[0] = m_scr[...].reshape(n_heads)
-            l_ref[0] = l_scr[...].reshape(n_heads)
-        else:
-            o = o_scr[...] / jnp.maximum(l_scr[...], 1e-30)[..., None]
-            out_ref[0] = o.reshape(n_heads, head_dim)
-
-
-def _paged_attention_mq_kernel(bt_ref, len_ref, win_ref, ok_ref, q_ref,
-                               k_ref, v_ref, *refs,
-                               fmt_kv: PositFormat | None, page_size: int,
-                               t_total: int, t_block: int, n_heads: int,
-                               n_kv_heads: int, head_dim: int,
-                               softcap_val: float, partials: bool):
-    """Multi-query grid: one launch covers T new tokens per slot.
-
-    Query row i of slot b sits at absolute position lengths[b] - T + i
-    (all T tokens already inserted); causality between the new tokens is
-    enforced by the same position mask that guards written-prefix reads.
-    Rows are independent, so any t_block tiling of T is bitwise identical
-    — t_block is the autotuned launch parameter.
-    """
-    if partials:
-        out_ref, m_ref, l_ref, m_scr, l_scr, o_scr = refs
-    else:
-        (out_ref, m_scr, l_scr, o_scr), m_ref, l_ref = refs, None, None
-    b = pl.program_id(0)
-    t = pl.program_id(1)
-    p = pl.program_id(2)
-    G = n_heads // n_kv_heads
-
-    @pl.when(p == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        o_scr[...] = jnp.zeros_like(o_scr)
-
-    if fmt_kv is None:
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-    else:
-        k = posit.decode(k_ref[0].astype(jnp.int32) & fmt_kv.mask, fmt_kv)
-        v = posit.decode(v_ref[0].astype(jnp.int32) & fmt_kv.mask, fmt_kv)
-    k = k.reshape(page_size, n_kv_heads, head_dim)
-    v = v.reshape(page_size, n_kv_heads, head_dim)
-
-    scale = 1.0 / math.sqrt(head_dim)
-    qg = q_ref[0].reshape(t_block, n_kv_heads, G, head_dim) \
-                 .astype(jnp.float32) * scale
-    s = jnp.einsum("thgd,khd->thgk", qg, k)  # [tb, Hkv, G, ps]
-    s = _softcap(s, softcap_val)
-
-    length = len_ref[b]
-    pos = p * page_size + jax.lax.iota(jnp.int32, page_size)
-    q_pos = length - t_total + t * t_block + jax.lax.iota(jnp.int32, t_block)
-    mask = (pos[None, :] <= q_pos[:, None]) \
-        & ((q_pos[:, None] - pos[None, :]) < win_ref[0]) \
-        & (ok_ref[b, p] > 0)
-    s = jnp.where(mask[:, None, None, :], s, _NEG)
-
-    m_prev, l_prev, o_prev = m_scr[...], l_scr[...], o_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    pr = jnp.exp(s - m_new[..., None])
-    pr = jnp.where(mask[:, None, None, :], pr, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    m_scr[...] = m_new
-    l_scr[...] = l_prev * corr + jnp.sum(pr, axis=-1)
-    o_scr[...] = o_prev * corr[..., None] \
-        + jnp.einsum("thgk,khd->thgd", pr, v)
+        m_prev, l_prev, o_prev = m_scr[i], l_scr[i], o_scr[i]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        pr = jnp.exp(s - m_new[..., None])
+        pr = jnp.where(mask[None, None, :], pr, 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        m_scr[i] = m_new
+        l_scr[i] = l_prev * corr + jnp.sum(pr, axis=-1)
+        o_scr[i] = o_prev * corr[..., None] \
+            + jnp.einsum("hgk,khd->hgd", pr, v)
 
     @pl.when(p == pl.num_programs(2) - 1)
     def _finalize():
+        for i in range(t_block):
+            if partials:
+                # leave the streaming state unnormalized: (o, m, l) per
+                # slot, to be log-sum-exp merged across kv_pages shards
+                # (ops.merge_attn_partials) before the single final
+                # normalization
+                out_ref[0, i] = o_scr[i].reshape(n_heads, head_dim)
+            else:
+                o = o_scr[i] / jnp.maximum(l_scr[i], 1e-30)[..., None]
+                out_ref[0, i] = o.reshape(n_heads, head_dim)
         if partials:
-            out_ref[0] = o_scr[...].reshape(t_block, n_heads, head_dim)
-            m_ref[0] = m_scr[...].reshape(t_block, n_heads)
-            l_ref[0] = l_scr[...].reshape(t_block, n_heads)
-        else:
-            o = o_scr[...] / jnp.maximum(l_scr[...], 1e-30)[..., None]
-            out_ref[0] = o.reshape(t_block, n_heads, head_dim)
-
-
-def _paged_attention_mq(q, k_pages, v_pages, block_tables, lengths, window,
-                        fmt_kv, softcap_val, interpret, page_ok, partials,
-                        t_block):
-    """4-D (multi-query) entry: q [B, T, Hq, Dh], grid (B, T//tb, M)."""
-    B, T, Hq, Dh = q.shape
-    n_pages, page_size, kvd = k_pages.shape
-    Hkv = kvd // Dh
-    if Hkv * Dh != kvd or Hq % Hkv:
-        raise ValueError(f"page feature dim {kvd} incompatible with "
-                         f"q heads {Hq} x head_dim {Dh}")
-    M = block_tables.shape[1]
-    if page_ok is None:
-        page_ok = jnp.ones((B, M), jnp.int32)
-    if t_block is None:
-        t_block = next(tb for tb in (8, 4, 2, 1) if T % tb == 0)
-    if T % t_block:
-        raise ValueError(f"t_block={t_block} must divide T={T}")
-
-    def _qmap(b, t, p, bt, ln, wn, ok):
-        return (b, t, 0, 0)
-
-    out_spec = pl.BlockSpec((1, t_block, Hq, Dh), _qmap)
-    out_shape = jax.ShapeDtypeStruct((B, T, Hq, Dh), jnp.float32)
-    if partials:
-        ml_spec = pl.BlockSpec((1, t_block, Hq),
-                               lambda b, t, p, bt, ln, wn, ok: (b, t, 0))
-        ml_shape = jax.ShapeDtypeStruct((B, T, Hq), jnp.float32)
-        out_specs = [out_spec, ml_spec, ml_spec]
-        out_shapes = [out_shape, ml_shape, ml_shape]
-    else:
-        out_specs, out_shapes = out_spec, out_shape
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, T // t_block, M),
-        in_specs=[
-            out_spec,
-            pl.BlockSpec((1, page_size, kvd),
-                         lambda b, t, p, bt, ln, wn, ok: (bt[b, p], 0, 0)),
-            pl.BlockSpec((1, page_size, kvd),
-                         lambda b, t, p, bt, ln, wn, ok: (bt[b, p], 0, 0)),
-        ],
-        out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((t_block, Hkv, Hq // Hkv), jnp.float32),
-            pltpu.VMEM((t_block, Hkv, Hq // Hkv), jnp.float32),
-            pltpu.VMEM((t_block, Hkv, Hq // Hkv, Dh), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _paged_attention_mq_kernel, fmt_kv=fmt_kv, page_size=page_size,
-        t_total=T, t_block=t_block, n_heads=Hq, n_kv_heads=Hkv, head_dim=Dh,
-        softcap_val=softcap_val, partials=partials)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shapes,
-        interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        ),
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      window.astype(jnp.int32), page_ok.astype(jnp.int32),
-      q.astype(jnp.float32), k_pages, v_pages)
+            m_ref[0] = m_scr[...]
+            l_ref[0] = l_scr[...]
 
 
 @functools.partial(
@@ -274,17 +149,18 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, window,
                    [B, T, Hq, Dh] for the multi-query grid — one launch
                    covers T new tokens per slot (token i of slot b at
                    absolute position lengths[b] - T + i, causally masked
-                   against both history and the other new tokens; T=1
-                   matches the 3-D path exactly).  `t_block` tiles T
-                   (autotuned; rows are independent so any tiling is
-                   bitwise identical); the 3-D path ignores it.
+                   against both history and the other new tokens; the 3-D
+                   form is exactly T=1).  `t_block` tiles T (autotuned;
+                   rows are independent so any tiling is bitwise
+                   identical); the 3-D path ignores it.
     k/v_pages    : [n_pages, page_size, Hkv*Dh] posit codes (int8/int16,
                    decoded in-kernel via fmt_kv) or float (fmt_kv=None).
     block_tables : [B, max_pages] int32 — page j holds the slot's positions
                    [j*page_size, (j+1)*page_size); unallocated entries may
                    point anywhere (they are masked by `lengths`).
     lengths      : [B] int32 valid positions per slot *including* the
-                   current token (written by the caller before this call).
+                   current token(s) (written by the caller before this
+                   call).
     window       : [1] int32 sliding-window size (>= max_seq = unbounded).
     page_ok      : optional [B, max_pages] mask (nonzero = contribute).
                    On a kv_pages-sharded pool each shard passes its
@@ -303,26 +179,39 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, window,
     if v_pages.shape != k_pages.shape:
         raise ValueError(f"k/v page pools differ: {k_pages.shape} vs "
                          f"{v_pages.shape}")
-    if q.ndim == 4:
-        return _paged_attention_mq(q, k_pages, v_pages, block_tables,
-                                   lengths, window, fmt_kv, softcap_val,
-                                   interpret, page_ok, partials, t_block)
-    B, Hq, Dh = q.shape
+    one_token = q.ndim == 3
+    if one_token:
+        q = q[:, None]
+        t_block = 1
+    B, T, Hq, Dh = q.shape
     n_pages, page_size, kvd = k_pages.shape
     Hkv = kvd // Dh
     if Hkv * Dh != kvd or Hq % Hkv:
         raise ValueError(f"page feature dim {kvd} incompatible with "
                          f"q heads {Hq} x head_dim {Dh}")
+    G = Hq // Hkv
     M = block_tables.shape[1]
     if page_ok is None:
         page_ok = jnp.ones((B, M), jnp.int32)
+    if t_block is None:
+        t_block = next(tb for tb in (8, 4, 2, 1) if T % tb == 0)
+    if T % t_block:
+        raise ValueError(f"t_block={t_block} must divide T={T}")
 
-    out_spec = pl.BlockSpec((1, Hq, Dh),
-                            lambda b, p, bt, ln, wn, ok: (b, 0, 0))
-    out_shape = jax.ShapeDtypeStruct((B, Hq, Dh), jnp.float32)
+    def _qmap(b, t, p, bt, ln, wn, ok):
+        return (b, t, 0, 0)
+
+    def _pmap(b, t, p, bt, ln, wn, ok):
+        return (bt[b, p], 0, 0)
+
+    # every block's last two dims span the whole array dims (Mosaic's
+    # (8, 128)-or-full rule): m/l come out as [B, T, Hkv, G] and are
+    # flattened to [B, T, Hq] outside the kernel
+    out_spec = pl.BlockSpec((1, t_block, Hq, Dh), _qmap)
+    out_shape = jax.ShapeDtypeStruct((B, T, Hq, Dh), jnp.float32)
     if partials:
-        ml_spec = pl.BlockSpec((1, Hq), lambda b, p, bt, ln, wn, ok: (b, 0))
-        ml_shape = jax.ShapeDtypeStruct((B, Hq), jnp.float32)
+        ml_spec = pl.BlockSpec((1, t_block, Hkv, G), _qmap)
+        ml_shape = jax.ShapeDtypeStruct((B, T, Hkv, G), jnp.float32)
         out_specs = [out_spec, ml_spec, ml_spec]
         out_shapes = [out_shape, ml_shape, ml_shape]
     else:
@@ -330,36 +219,39 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, window,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(B, M),
+        grid=(B, T // t_block, M),
         in_specs=[
-            pl.BlockSpec((1, Hq, Dh), lambda b, p, bt, ln, wn, ok: (b, 0, 0)),
-            pl.BlockSpec((1, page_size, kvd),
-                         lambda b, p, bt, ln, wn, ok: (bt[b, p], 0, 0)),
-            pl.BlockSpec((1, page_size, kvd),
-                         lambda b, p, bt, ln, wn, ok: (bt[b, p], 0, 0)),
+            out_spec,
+            pl.BlockSpec((1, page_size, kvd), _pmap),
+            pl.BlockSpec((1, page_size, kvd), _pmap),
         ],
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((Hkv, Hq // Hkv), jnp.float32),
-            pltpu.VMEM((Hkv, Hq // Hkv), jnp.float32),
-            pltpu.VMEM((Hkv, Hq // Hkv, Dh), jnp.float32),
+            pltpu.VMEM((t_block, Hkv, G), jnp.float32),
+            pltpu.VMEM((t_block, Hkv, G), jnp.float32),
+            pltpu.VMEM((t_block, Hkv, G, Dh), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _paged_attention_kernel, fmt_kv=fmt_kv, page_size=page_size,
-        n_heads=Hq, n_kv_heads=Hkv, head_dim=Dh, softcap_val=softcap_val,
-        partials=partials)
-    return pl.pallas_call(
+        t_total=T, t_block=t_block, n_heads=Hq, n_kv_heads=Hkv, head_dim=Dh,
+        softcap_val=softcap_val, partials=partials)
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       window.astype(jnp.int32), page_ok.astype(jnp.int32),
       q.astype(jnp.float32), k_pages, v_pages)
+    if partials:
+        o, m, l = out
+        out = (o, m.reshape(B, T, Hq), l.reshape(B, T, Hq))
+        return tuple(x[:, 0] for x in out) if one_token else out
+    return out[:, 0] if one_token else out
 
 
 # ---------------------------------------------------------------------------
@@ -367,10 +259,30 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, window,
 # ---------------------------------------------------------------------------
 
 
+# Untuned vocab tile budget: the decoded f32 weight tile, its code tile
+# (double-buffered) and the in-kernel temporaries must fit the 16 MiB
+# scoped VMEM of a TPU v5e — a 2048 x 2560 tile of gemma3-4b's head needs
+# 20 MiB there and is refused.
+_V_TILE_BYTES = 4 << 20
+
+
+def default_v_block(V: int, D: int) -> int:
+    """V itself when the whole [D, V] f32 tile fits `_V_TILE_BYTES`;
+    otherwise the widest power-of-two tile of at least 128 lanes that fits
+    (128 when none does).  A tile that does not divide V leaves a partial
+    last tile, which the kernel masks."""
+    if V * D * 4 <= _V_TILE_BYTES:
+        return V
+    vb = 128
+    while 2 * vb < V and 2 * vb * D * 4 <= _V_TILE_BYTES:
+        vb *= 2
+    return vb
+
+
 def _decode_sample_kernel(x_ref, w_ref, *refs, plan: str,
                           fmt_w: PositFormat | None, transpose: bool,
                           greedy: bool, top_k: int, softcap_val: float,
-                          v_block: int, n_vt: int, n_phase: int):
+                          v_block: int, vocab: int, n_vt: int, n_phase: int):
     if greedy:
         t_ref, tok_ref, *scr = refs
         noise_ref = None
@@ -395,8 +307,10 @@ def _decode_sample_kernel(x_ref, w_ref, *refs, plan: str,
             wq = w
         x = x_ref[...]
         if plan == "fused":
-            # ops.matmul_posit_weights: f32 activations x exact f32 decode
+            # ops.matmul_posit_weights: f32 activations x exact f32 decode,
+            # both kept at f32 precision on the MXU
             l = jnp.dot(x.astype(jnp.float32), wq,
+                        precision=jax.lax.Precision.HIGHEST,
                         preferred_element_type=jnp.float32)
         else:
             # fake_quant: unpack to x.dtype, dot in x.dtype, f32 output
@@ -407,12 +321,20 @@ def _decode_sample_kernel(x_ref, w_ref, *refs, plan: str,
     def _scaled():
         return _logits() / jnp.maximum(t_ref[0], 1e-6)
 
+    def _in_vocab(y):
+        # the last tile may run past V: its columns there hold whatever the
+        # block padding read, and must never be sampled
+        if vocab % v_block == 0:
+            return y
+        col = t * v_block + jax.lax.broadcasted_iota(jnp.int32, y.shape, 1)
+        return jnp.where(col < vocab, y, -jnp.inf)
+
     if n_phase == 2:
         # phase 0: stream the per-row top-k values into kbuf so the argmax
         # phase can read the exact k-th largest (== sort(l)[..., -top_k])
         @pl.when(ph == 0)
         def _topk():
-            l = _scaled()
+            l = _in_vocab(_scaled())
 
             @pl.when(t == 0)
             def _init_kbuf():
@@ -441,6 +363,7 @@ def _decode_sample_kernel(x_ref, w_ref, *refs, plan: str,
                 l = jnp.where(l >= kth[:, None], l, -1e30)
             # categorical(key, l) == argmax(gumbel_noise + l)
             y = noise_ref[...] + l
+        y = _in_vocab(y)
 
         @pl.when(t == 0)
         def _init_best():
@@ -493,20 +416,21 @@ def decode_sample(x, w, noise=None, temperature=None, *, plan: str = "fused",
     top_k       : 0 (or >= V) disables the top-k filter; otherwise a
                   streaming k-buffer phase reproduces `sort(l)[..., -k]`
                   exactly before the filtered gumbel argmax.
-    v_block     : vocab tile width (must divide V); None = whole vocab in
-                  one grid step.  Tiling the vocab axis only (rows stay
-                  whole) keeps the f32 dot bitwise identical to the
-                  untiled `logits_head` matmul.
+    v_block     : vocab tile width; None = `default_v_block`, sized so
+                  the decoded f32 weight tile fits `_V_TILE_BYTES`.  A
+                  tile that does not divide V leaves a partial last tile
+                  whose columns past V are masked out of the sampler.
+                  Tiling the vocab axis only (rows stay whole) keeps the
+                  f32 dot bitwise identical to the untiled `logits_head`
+                  matmul.
 
     Returns [B] int32 sampled tokens, bit-identical to running
     `logits_head` and the engine sampler as separate device programs.
     """
     B, D = x.shape
     V = w.shape[0] if transpose else w.shape[1]
-    vb = V if v_block is None else int(v_block)
-    if V % vb:
-        raise ValueError(f"v_block {vb} must divide vocab {V}")
-    n_vt = V // vb
+    vb = default_v_block(V, D) if v_block is None else min(int(v_block), V)
+    n_vt = pl.cdiv(V, vb)
     topk_active = (not greedy) and 0 < top_k < V
     n_phase = 2 if topk_active else 1
     if temperature is None:
@@ -534,7 +458,7 @@ def decode_sample(x, w, noise=None, temperature=None, *, plan: str = "fused",
     kernel = functools.partial(
         _decode_sample_kernel, plan=plan, fmt_w=fmt_w, transpose=transpose,
         greedy=greedy, top_k=int(top_k), softcap_val=softcap_val,
-        v_block=vb, n_vt=n_vt, n_phase=n_phase)
+        v_block=vb, vocab=V, n_vt=n_vt, n_phase=n_phase)
     tok = pl.pallas_call(
         kernel,
         grid=(n_phase, n_vt),
@@ -543,7 +467,7 @@ def decode_sample(x, w, noise=None, temperature=None, *, plan: str = "fused",
         out_shape=jax.ShapeDtypeStruct((1, B), jnp.int32),
         scratch_shapes=scratch,
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
     )(*inputs)

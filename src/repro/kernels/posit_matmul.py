@@ -7,8 +7,9 @@ TPU-native realization tiles a GEMM over (M/bm, N/bn, K/bk):
   * decode: posit tiles (int16/int8 in HBM -> VMEM) are decoded to exact
     f32 *inside* the kernel (VPU bit ops) — never materialized in HBM.
     2 decodes per input element, total; no discrete-unit re-decoding.
-  * accumulate: the MXU matmul accumulates in an f32 VMEM scratch across
-    the K grid dimension — the W_m-wide aligned accumulator analogue.
+  * accumulate: the MXU matmul (at f32 operand precision) accumulates in
+    an f32 VMEM scratch across the K grid dimension — the W_m-wide aligned
+    accumulator analogue.
   * encode: on the last K step the f32 tile is rounded *once* into the
     output posit format — the single-rounding fused property.
 
@@ -35,8 +36,10 @@ from repro.core.formats import PositFormat
 # MXU-aligned tile defaults (128x128 systolic array; K tiled for VMEM).
 _BM, _BN, _BK = 256, 256, 512
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+# The MXU's default precision rounds f32 operands to bf16 (8 significant
+# bits); a P(16,2) value carries up to 12.  HIGHEST keeps the decoded
+# operands whole, so the one rounding is the final encode.
+F32 = jax.lax.Precision.HIGHEST
 
 
 def _fused_matmul_kernel(a_ref, b_ref, out_ref, acc_ref, *,
@@ -49,8 +52,10 @@ def _fused_matmul_kernel(a_ref, b_ref, out_ref, acc_ref, *,
     # S1 (decode) on the VPU — exact f32 values of the posit codes
     a = posit.decode(a_ref[...].astype(jnp.int32) & fmt_a.mask, fmt_a)
     b = posit.decode(b_ref[...].astype(jnp.int32) & fmt_b.mask, fmt_b)
-    # S2-S4 (multiply + wide accumulate) on the MXU
-    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    # S2-S4 (multiply + wide accumulate) on the MXU, at f32 operand
+    # precision: the default would round the decoded operands to bf16
+    acc_ref[...] += jnp.dot(a, b, precision=F32,
+                            preferred_element_type=jnp.float32)
 
     # S5-S6 (normalize + single rounding/encode) on the final K step
     @pl.when(pl.program_id(2) == n_k - 1)
@@ -81,7 +86,8 @@ def _grouped_matmul_kernel(a_ref, b_ref, out_ref, acc_ref, *,
     else:
         a = posit.decode(a_ref[0].astype(jnp.int32) & fmt_a.mask, fmt_a)
     b = posit.decode(b_ref[0].astype(jnp.int32) & fmt_b.mask, fmt_b)
-    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot(a, b, precision=F32,
+                            preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(3) == n_k - 1)
     def _finalize():
@@ -153,7 +159,7 @@ def posit_matmul_grouped(a, b_codes, fmt_a: PositFormat | None,
         out_specs=pl.BlockSpec((1, bm_, bn_), lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -215,7 +221,7 @@ def posit_matmul(a_codes, b_codes, fmt_a: PositFormat, fmt_b: PositFormat,
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

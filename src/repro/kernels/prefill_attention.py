@@ -64,9 +64,6 @@ from repro.core.formats import PositFormat
 
 _NEG = -2.0e38
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-
 def _softcap(x, cap: float):
     if cap <= 0:
         return x
@@ -87,6 +84,11 @@ def _fused_prefill_kernel(bt_ref, st_ref, win_ref, ok_ref, hbt_ref, q_ref,
                           n_pages_per_slot: int, n_heads: int,
                           n_kv_heads: int, head_dim: int, softcap_val: float,
                           flash_chunk: int, global_hist: bool):
+    # Layout (set up by the wrapper, so that no in-kernel reshape splits a
+    # tiled dim): q is [Hkv, C*G, Dh] with rows ordered (query, group) —
+    # the row order flash_attention's GQA einsum contracts in — and the
+    # chunk k/v are [Hkv, C, Dh].  History is staged per kv head into
+    # [Hkv, rows, Dh] buffers; softmax state is [Hkv, C*G, 1|Dh].
     if global_hist:
         hkp_ref, hvp_ref, *refs = refs
     else:
@@ -95,9 +97,9 @@ def _fused_prefill_kernel(bt_ref, st_ref, win_ref, ok_ref, hbt_ref, q_ref,
     b = pl.program_id(0)
     p = pl.program_id(1)
     ps, C, M = page_size, chunk, n_pages_per_slot
-    F = n_kv_heads * head_dim
     G = n_heads // n_kv_heads
     Dh = head_dim
+    CG = C * G
     # flash_attention's chunk geometry, replayed statically: the key span
     # [history S_h | chunk C | pad] is scanned in ck-row chunks; the first
     # n_hist_full chunks are pure history and stream through the staging
@@ -108,7 +110,6 @@ def _fused_prefill_kernel(bt_ref, st_ref, win_ref, ok_ref, hbt_ref, q_ref,
     n_hist_full = S_h // ck
     h_rem = S_h - n_hist_full * ck
     n_tail = -(-(h_rem + C) // ck)
-    tail_pad = n_tail * ck - (h_rem + C)
     R = min(S_h, ck) // ps  # staging ring size, in pages
     start = st_ref[b]
 
@@ -117,9 +118,9 @@ def _fused_prefill_kernel(bt_ref, st_ref, win_ref, ok_ref, hbt_ref, q_ref,
 
         @pl.when(p == 0)
         def _init_state():
-            m_scr[...] = jnp.full((n_kv_heads, G, C), _NEG, jnp.float32)
-            l_scr[...] = jnp.zeros((n_kv_heads, G, C), jnp.float32)
-            o_scr[...] = jnp.zeros((n_kv_heads, G, C, Dh), jnp.float32)
+            m_scr[...] = jnp.full(m_scr.shape, _NEG, jnp.float32)
+            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+            o_scr[...] = jnp.zeros(o_scr.shape, jnp.float32)
 
     # Snapshot the page before any aliased output write: history staging
     # and the read side of the merge must see pre-insert pool content
@@ -128,62 +129,66 @@ def _fused_prefill_kernel(bt_ref, st_ref, win_ref, ok_ref, hbt_ref, q_ref,
     old_v = vp_ref[0]
     src_k = hkp_ref[0] if global_hist else old_k
     src_v = hvp_ref[0] if global_hist else old_v
-    stage = (p % R) * ps
-    hk_scr[pl.ds(stage, ps)] = _decode_hist(src_k, fmt_kv, compute_dtype,
-                                            hk_scr.dtype)
-    hv_scr[pl.ds(stage, ps)] = _decode_hist(src_v, fmt_kv, compute_dtype,
-                                            hv_scr.dtype)
+    stage = pl.multiple_of((p % R) * ps, ps)
+    dk = _decode_hist(src_k, fmt_kv, compute_dtype, hk_scr.dtype)
+    dv = _decode_hist(src_v, fmt_kv, compute_dtype, hv_scr.dtype)
+    for h in range(n_kv_heads):
+        hk_scr[h, pl.ds(stage, ps)] = dk[:, h * Dh:(h + 1) * Dh]
+        hv_scr[h, pl.ds(stage, ps)] = dv[:, h * Dh:(h + 1) * Dh]
 
     # ---- in-kernel encode + page write ------------------------------------
     # rows r of page p hold absolute positions p*ps + r; the chunk occupies
     # [start, start + C).  Select each covered row's raw chunk k/v with a
     # 0/1 matmul (exact: one surviving term per row), encode, merge with the
     # old page content, write.  The output index_map redirects pages outside
-    # the chunk span (or not owned by this shard) to the trash page.
-    rpos = p * ps + jax.lax.iota(jnp.int32, ps)
-    j = rpos - start
-    in_chunk = (j >= 0) & (j < C)
-    sel = (j[:, None] == jax.lax.broadcasted_iota(jnp.int32, (ps, C), 1))
+    # the chunk span (or not owned by this shard) to the trash page.  Row
+    # indices are built as a [ps, 1] column directly: Mosaic cannot reshape
+    # a 1-D bool vector into a column.
+    j = p * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0) - start
+    wm = (j >= 0) & (j < C)
+    sel = (j == jax.lax.broadcasted_iota(jnp.int32, (ps, C), 1))
     sel_f = sel.astype(jnp.float32)
-    kc = k_ref[0].reshape(C, F)
-    vc = v_ref[0].reshape(C, F)
-    k_rows = jnp.dot(sel_f, kc.astype(jnp.float32)).astype(kc.dtype)
-    v_rows = jnp.dot(sel_f, vc.astype(jnp.float32)).astype(vc.dtype)
+    kc = jnp.concatenate([k_ref[0, h] for h in range(n_kv_heads)], axis=-1)
+    vc = jnp.concatenate([v_ref[0, h] for h in range(n_kv_heads)], axis=-1)
+    # (HIGHEST: the MXU default would round f32 rows to bf16)
+    k_rows = jnp.dot(sel_f, kc.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST).astype(kc.dtype)
+    v_rows = jnp.dot(sel_f, vc.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST).astype(vc.dtype)
     if fmt_kv is None:
         k_codes = k_rows.astype(compute_dtype)
         v_codes = v_rows.astype(compute_dtype)
     else:
         k_codes = posit.encode(k_rows, fmt_kv)
         v_codes = posit.encode(v_rows, fmt_kv)
-    wm = in_chunk[:, None]
     kp_out[0] = jnp.where(wm, k_codes.astype(old_k.dtype), old_k)
     vp_out[0] = jnp.where(wm, v_codes.astype(old_v.dtype), old_v)
 
     # ---- running flash softmax --------------------------------------------
     scale = 1.0 / math.sqrt(Dh)
-    q_pos = start + jax.lax.iota(jnp.int32, C)
+    # query row r is token r // G of the chunk
+    q_pos = start + jax.lax.broadcasted_iota(jnp.int32, (CG, 1), 0) // G
 
     def _qg():
-        return q_ref[0].reshape(C, n_kv_heads, G, Dh) \
-                       .astype(jnp.float32) * scale
+        return q_ref[0].astype(jnp.float32) * scale
 
     def _flash_step(m, l, o, kb, vb, kv_pos, qg):
         # one chunk of flash_attention's streaming scan, replayed verbatim
-        # (B=1 blocks)
-        s = jnp.einsum("qhgd,khd->hgqk", qg, kb.astype(jnp.float32))
+        # (B=1 blocks); kv_pos is a [1, ck] row
+        s = jnp.einsum("hqd,hkd->hqk", qg, kb.astype(jnp.float32))
         s = _softcap(s, softcap_val)
-        mask = kv_pos[None, :] >= 0
-        mask &= q_pos[:, None] >= kv_pos[None, :]
-        mask &= (q_pos[:, None] - kv_pos[None, :]) < win_ref[0]
-        s = jnp.where(mask[None, None, :, :], s, _NEG)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        pr = jnp.exp(s - m_new[..., None])
+        mask = kv_pos >= 0
+        mask &= q_pos >= kv_pos
+        mask &= (q_pos - kv_pos) < win_ref[0]
+        s = jnp.where(mask[None], s, _NEG)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        pr = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(pr, axis=-1)
-        pv = jnp.einsum("hgqk,khd->hgqd", pr, vb.astype(jnp.float32))
+        l_new = l * corr + jnp.sum(pr, axis=-1, keepdims=True)
+        pv = jnp.einsum("hqk,hkd->hqd", pr, vb.astype(jnp.float32))
         # keep the o*corr term: 0.0*corr + (-0.0) is +0.0, matching flash;
         # writing `pv` alone would flip those signs
-        o_new = o * corr[..., None] + pv
+        o_new = o * corr + pv
         return m_new, l_new, o_new
 
     if n_hist_full:
@@ -191,14 +196,12 @@ def _fused_prefill_kernel(bt_ref, st_ref, win_ref, ok_ref, hbt_ref, q_ref,
         # fold it into the running state
         @pl.when(((p + 1) % R == 0) & (p + 1 <= n_hist_full * R))
         def _hist_step():
-            qg = _qg()
-            kb = hk_scr[...].reshape(ck, n_kv_heads, Dh)
-            vb = hv_scr[...].reshape(ck, n_kv_heads, Dh)
             base = (p + 1) * ps - ck
-            pos = base + jax.lax.iota(jnp.int32, ck)
+            pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, ck), 1)
             pos = jnp.where(pos < start, pos, -1)
             m_new, l_new, o_new = _flash_step(
-                m_scr[...], l_scr[...], o_scr[...], kb, vb, pos, qg)
+                m_scr[...], l_scr[...], o_scr[...], hk_scr[:, :ck],
+                hv_scr[:, :ck], pos, _qg())
             m_scr[...] = m_new
             l_scr[...] = l_new
             o_scr[...] = o_new
@@ -206,41 +209,35 @@ def _fused_prefill_kernel(bt_ref, st_ref, win_ref, ok_ref, hbt_ref, q_ref,
     # ---- tail chunks + finalize on the slot's last page step --------------
     @pl.when(p == M - 1)
     def _attend():
-        kdt = k_ref.dtype
         qg = _qg()
         if state:
             m = m_scr[...]
             l = l_scr[...]
             o = o_scr[...]
         else:
-            m = jnp.full((n_kv_heads, G, C), _NEG, jnp.float32)
-            l = jnp.zeros((n_kv_heads, G, C), jnp.float32)
-            o = jnp.zeros((n_kv_heads, G, C, Dh), jnp.float32)
-        parts_k, parts_v, parts_pos = [], [], []
-        if h_rem:
-            hk = hk_scr[...][:h_rem].reshape(h_rem, n_kv_heads, Dh)
-            hv = hv_scr[...][:h_rem].reshape(h_rem, n_kv_heads, Dh)
-            hp = n_hist_full * ck + jax.lax.iota(jnp.int32, h_rem)
-            parts_k.append(hk)
-            parts_v.append(hv)
-            parts_pos.append(jnp.where(hp < start, hp, -1))
-        parts_k.append(k_ref[0])
-        parts_v.append(v_ref[0])
-        parts_pos.append(q_pos)
-        if tail_pad:
-            parts_k.append(jnp.zeros((tail_pad, n_kv_heads, Dh), kdt))
-            parts_v.append(jnp.zeros((tail_pad, n_kv_heads, Dh), kdt))
-            parts_pos.append(jnp.full((tail_pad,), -1, jnp.int32))
-        k_tail = jnp.concatenate(parts_k, axis=0)
-        v_tail = jnp.concatenate(parts_v, axis=0)
-        pos_tail = jnp.concatenate(parts_pos)
+            m = jnp.full((n_kv_heads, CG, 1), _NEG, jnp.float32)
+            l = jnp.zeros((n_kv_heads, CG, 1), jnp.float32)
+            o = jnp.zeros((n_kv_heads, CG, Dh), jnp.float32)
+        # the staging ring already holds the history remainder in rows
+        # [0, h_rem); append the raw chunk and zero the pad behind it
+        n_rows = n_tail * ck
+        hk_scr[:, h_rem:h_rem + C] = k_ref[0].astype(hk_scr.dtype)
+        hv_scr[:, h_rem:h_rem + C] = v_ref[0].astype(hv_scr.dtype)
+        if n_rows > h_rem + C:
+            pad = (n_kv_heads, n_rows - h_rem - C, Dh)
+            hk_scr[:, h_rem + C:n_rows] = jnp.zeros(pad, hk_scr.dtype)
+            hv_scr[:, h_rem + C:n_rows] = jnp.zeros(pad, hv_scr.dtype)
+        r = jax.lax.broadcasted_iota(jnp.int32, (1, n_rows), 1)
+        hp = n_hist_full * ck + r
+        pos_tail = jnp.where(
+            r < h_rem, jnp.where(hp < start, hp, -1),
+            jnp.where(r < h_rem + C, start + r - h_rem, -1))
         for jt in range(n_tail):
             sl = slice(jt * ck, (jt + 1) * ck)
-            m, l, o = _flash_step(m, l, o, k_tail[sl], v_tail[sl],
-                                  pos_tail[sl], qg)
-        o = o / jnp.maximum(l, 1e-30)[..., None]
-        out = jnp.moveaxis(o, 2, 0).reshape(C, n_heads, Dh)
-        attn_ref[0] = out.astype(q_ref.dtype)
+            m, l, o = _flash_step(m, l, o, hk_scr[:, sl], hv_scr[:, sl],
+                                  pos_tail[:, sl], qg)
+        o = o / jnp.maximum(l, 1e-30)
+        attn_ref[0] = o.astype(attn_ref.dtype)
 
 
 @functools.partial(
@@ -321,10 +318,18 @@ def prefill_attention_paged(q, k, v, k_pages, v_pages, block_tables, starts,
         w = (pstart < st[b] + C) & (pstart + ps > st[b]) & (ok[b, p] > 0)
         return (jnp.where(w, bt[b, p], 0), 0, 0)
 
-    chunk_spec = pl.BlockSpec((1, C, Hkv, Dh), _qmap)
+    # kernel-side layout: heads leading, so every in-kernel op works on
+    # whole [rows, Dh] tiles (pure relayouts here and on the way out)
+    G = Hq // Hkv
+    qt = q.reshape(B, C, Hkv, G, Dh).transpose(0, 2, 1, 3, 4) \
+          .reshape(B, Hkv, C * G, Dh)
+    kt = k.transpose(0, 2, 1, 3)
+    vt = v.transpose(0, 2, 1, 3)
+    q_spec = pl.BlockSpec((1, Hkv, C * G, Dh), _qmap)
+    chunk_spec = pl.BlockSpec((1, Hkv, C, Dh), _qmap)
     page_spec = pl.BlockSpec((1, ps, kvd), _pmap)
-    in_specs = [pl.BlockSpec((1, C, Hq, Dh), _qmap), chunk_spec, chunk_spec]
-    inputs = [q, k, v]
+    in_specs = [q_spec, chunk_spec, chunk_spec]
+    inputs = [qt, kt, vt]
     if global_hist:
         hist_spec = pl.BlockSpec((1, ps, kvd), _hmap)
         in_specs += [hist_spec, hist_spec]
@@ -336,22 +341,23 @@ def prefill_attention_paged(q, k, v, k_pages, v_pages, block_tables, starts,
     kp_idx = 5 + len(in_specs) - 2
     aliases = {kp_idx: 1, kp_idx + 1: 2}
 
-    buf_rows = min(S_h, ck)
-    scratch = [pltpu.VMEM((buf_rows, kvd), k.dtype),
-               pltpu.VMEM((buf_rows, kvd), v.dtype)]
+    # staging ring (history) that also holds the tail [rem | chunk | pad]
+    n_tail = -(-(S_h - n_hist_full * ck + C) // ck)
+    buf_rows = max(min(S_h, ck), n_tail * ck)
+    scratch = [pltpu.VMEM((Hkv, buf_rows, Dh), k.dtype),
+               pltpu.VMEM((Hkv, buf_rows, Dh), v.dtype)]
     if n_hist_full:
-        G = Hq // Hkv
-        scratch += [pltpu.VMEM((Hkv, G, C), jnp.float32),
-                    pltpu.VMEM((Hkv, G, C), jnp.float32),
-                    pltpu.VMEM((Hkv, G, C, Dh), jnp.float32)]
+        scratch += [pltpu.VMEM((Hkv, C * G, 1), jnp.float32),
+                    pltpu.VMEM((Hkv, C * G, 1), jnp.float32),
+                    pltpu.VMEM((Hkv, C * G, Dh), jnp.float32)]
 
     out_specs = [
-        pl.BlockSpec((1, C, Hq, Dh), _qmap),
+        q_spec,
         pl.BlockSpec((1, ps, kvd), _wmap),
         pl.BlockSpec((1, ps, kvd), _wmap),
     ]
     out_shapes = [
-        jax.ShapeDtypeStruct((B, C, Hq, Dh), q.dtype),
+        jax.ShapeDtypeStruct(qt.shape, q.dtype),
         jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
         jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
     ]
@@ -379,8 +385,10 @@ def prefill_attention_paged(q, k, v, k_pages, v_pages, block_tables, starts,
         out_shape=out_shapes,
         input_output_aliases=aliases,
         interpret=interpret,
-        compiler_params=_CompilerParams(**cp_kwargs),
+        compiler_params=pltpu.CompilerParams(**cp_kwargs),
     )(block_tables.astype(jnp.int32), starts.astype(jnp.int32),
       window.astype(jnp.int32), page_ok.astype(jnp.int32),
       hbt.astype(jnp.int32), *inputs)
+    attn = attn.reshape(B, Hkv, C, G, Dh).transpose(0, 2, 1, 3, 4) \
+               .reshape(B, C, Hq, Dh)
     return attn, k_new, v_new

@@ -33,13 +33,15 @@ def posit_matmul_ref(a_codes, b_codes, fmt_a: PositFormat, fmt_b: PositFormat,
     are bit-identical (f32 addition is order-sensitive)."""
     a = decode_ref(a_codes, fmt_a)
     b = decode_ref(b_codes, fmt_b)
+    hi = jax.lax.Precision.HIGHEST  # f32 operands, as in the kernel
     if bk is None or bk >= a.shape[-1]:
-        out = jnp.dot(a, b, preferred_element_type=jnp.float32)
+        out = jnp.dot(a, b, precision=hi, preferred_element_type=jnp.float32)
     else:
         K = a.shape[-1]
         out = jnp.zeros((a.shape[0], b.shape[1]), jnp.float32)
         for k0 in range(0, K, bk):
             out = out + jnp.dot(a[:, k0:k0 + bk], b[k0:k0 + bk, :],
+                                precision=hi,
                                 preferred_element_type=jnp.float32)
     if fmt_out is None:
         return out

@@ -31,14 +31,10 @@ import jax.numpy as jnp
 
 from repro.core import posit
 from repro.core.formats import P8_2, P13_2, P16_1, P16_2
-from repro.kernels import autotune
+from repro.kernels import autotune, ops
 from repro.kernels import paged_attention as paged_attention_mod
 from repro.kernels import prefill_attention as prefill_attention_mod
 from repro.kernels import posit_codec, posit_matmul
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # serving-representative sweep points: (shape, fmts) per kernel.  Shapes
@@ -84,7 +80,7 @@ def sweep_points(quick: bool):
 def _runner(kernel: str, shape, fmts, rng):
     """Build `run(params) -> thunk` for one sweep point (see
     autotune.sweep); inputs are generated once and closed over."""
-    interp = _interpret()
+    interp = ops.interpret_mode()
     if kernel in ("posit_codec.decode", "posit_codec.encode"):
         (fmt,) = fmts
         vals = jnp.asarray(rng.normal(0, 1, shape), jnp.float32)
@@ -184,7 +180,7 @@ def main():
 
     rng = np.random.default_rng(0)
     cache = autotune.AutotuneCache()
-    print(f"backend: {cache.backend} (interpret={_interpret()})")
+    print(f"backend: {cache.backend} (interpret={ops.interpret_mode()})")
     for kernel, points in sweep_points(args.quick).items():
         for shape, fmts in points:
             run = _runner(kernel, shape, fmts, rng)

@@ -28,7 +28,7 @@ import jax           # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro import configs                     # noqa: E402
-from repro.launch.mesh import make_production_mesh, HW  # noqa: E402
+from repro.launch.mesh import PEAKS, V5E, make_production_mesh  # noqa: E402
 from repro.models import api                  # noqa: E402
 from repro.models.config import shape_by_name, ALL_SHAPES  # noqa: E402
 from repro.models.module import ParamSpec, abstract_params, param_bytes  # noqa: E402
@@ -145,7 +145,7 @@ def build_lowered(cfg, shape, mesh, accum=None):
     batch_abs = api.input_specs(cfg, shape)
     batch_sh = _batch_shardings(batch_abs, mesh)
 
-    with mesh:
+    with sharding.use_mesh(mesh):
         if shape.kind == "train":
             opt = _opt_for(cfg)
             opt_abs = jax.eval_shape(opt.init, params_abs)
@@ -216,9 +216,10 @@ def analyze(lowered, compiled, cfg, shape, mesh, compile_s):
             "total_bytes": hlo["collective_bytes"]}
 
     # --- roofline terms (per-device program -> per-chip seconds) ----------
-    t_compute = flops / HW["peak_flops_bf16"]
-    t_memory = bytes_acc / HW["hbm_bw"]
-    t_coll = coll["total_bytes"] / HW["ici_bw"]
+    hw = PEAKS[V5E]  # the production mesh is built of v5e chips
+    t_compute = flops / hw["peak_flops_bf16"]
+    t_memory = bytes_acc / hw["hbm_bw"]
+    t_coll = coll["total_bytes"] / hw["ici_bw"]
     # model flops: 6*N*D for train, 2*N*D for a forward/prefill token batch
     from repro.models.module import param_count
     n_params = param_count(api.param_specs(cfg))

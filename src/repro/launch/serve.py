@@ -26,9 +26,11 @@ import time
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs
 from repro.core.quant import policy_by_name
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.serve import Request, ServingEngine
 
@@ -66,11 +68,19 @@ def main():
     cfg = cfg.replace(quant=policy_by_name(args.quant))
     if cfg.is_encoder:
         raise SystemExit(f"{cfg.name} is encoder-only; nothing to serve")
-    params = api.init(jax.random.key(0), cfg)
+    enable_compile_cache()
     mesh = None
     if args.mesh_model > 1:
         from repro.launch.mesh import make_serving_mesh
         mesh = make_serving_mesh(args.mesh_model)
+    # a policy with posit weights gets them built straight into codes, one
+    # leaf at a time (replicated over the mesh as they are built): full
+    # widths fit where the float tree would not
+    if cfg.quant.weights is not None:
+        rep = None if mesh is None else NamedSharding(mesh, P())
+        params = api.init_packed(jax.random.key(0), cfg, sharding=rep)
+    else:
+        params = api.init(jax.random.key(0), cfg)
     engine = ServingEngine(cfg, params, batch_slots=args.slots,
                            max_seq=args.max_seq, paged=not args.dense,
                            page_size=args.page_size,

@@ -11,6 +11,7 @@ for the dry-run, and `cache_specs` for decode-state dry-runs.
 
 Posit-packed checkpoints (re-exported from `packing`): `pack_params` /
 `unpack_params` convert qdot weights to/from posit code arrays,
+`init_packed` builds the packed tree straight from a seed,
 `packed_param_specs` types the restore tree, `pack_manifest` tags the
 checkpoint.  apply/prefill/decode_step accept packed params transparently
 (the GEMM dispatch layer detects code containers).
@@ -26,7 +27,7 @@ from . import transformer, moe, mamba, hybrid
 from .config import ModelConfig, ShapeConfig
 from .module import ParamSpec, abstract_params, init_params
 from .packing import (pack_params, unpack_params, packed_param_specs,  # noqa: F401
-                      pack_manifest, weight_bytes)
+                      pack_manifest, weight_bytes, init_packed)
 from .paged import PagedLayout  # noqa: F401  (re-exported serving layout)
 
 

@@ -131,7 +131,11 @@ def flash_attention(q, k, v, q_pos, kv_pos, *, causal: bool,
     _, Skv, Hkv, _ = k.shape
     G = Hq // Hkv
     scale = 1.0 / math.sqrt(D)
-    qg = q.reshape(B, Sq, Hkv, G, D).astype(jnp.float32) * scale
+    # heads-leading layout with query rows ordered (query, group): the
+    # layout the fused prefill kernel computes in, so both agree bitwise
+    qg = q.reshape(B, Sq, Hkv, G, D).transpose(0, 2, 1, 3, 4) \
+          .reshape(B, Hkv, Sq * G, D).astype(jnp.float32) * scale
+    qp = jnp.repeat(q_pos, G, axis=1)  # [B, Sq*G] position of each row
 
     ck = min(chunk_k, Skv)
     n_chunks = -(-Skv // ck)
@@ -140,37 +144,37 @@ def flash_attention(q, k, v, q_pos, kv_pos, *, causal: bool,
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
         kv_pos = jnp.pad(kv_pos, ((0, 0), (0, pad)), constant_values=-1)
-    kc = k.reshape(B, n_chunks, ck, Hkv, D)
-    vc = v.reshape(B, n_chunks, ck, Hkv, D)
-    pc = kv_pos.reshape(B, n_chunks, ck)
+    # [n_chunks, B, Hkv, ck, D] / [n_chunks, B, ck]
+    kc = k.reshape(B, n_chunks, ck, Hkv, D).transpose(1, 0, 3, 2, 4)
+    vc = v.reshape(B, n_chunks, ck, Hkv, D).transpose(1, 0, 3, 2, 4)
+    pc = jnp.moveaxis(kv_pos.reshape(B, n_chunks, ck), 1, 0)
 
     def step(carry, blk):
         m, l, o = carry
-        kb, vb, pb = blk  # [B, ck, Hkv, D], [B, ck]
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, kb.astype(jnp.float32))
+        kb, vb, pb = blk  # [B, Hkv, ck, D], [B, ck]
+        s = jnp.einsum("bhqd,bhkd->bhqk", qg, kb.astype(jnp.float32))
         s = softcap(s, softcap_val)
-        mask = pb[:, None, None, None, :] >= 0
+        mask = pb[:, None, None, :] >= 0
         if causal:
-            mask &= q_pos[:, None, None, :, None] >= pb[:, None, None, None, :]
+            mask &= qp[:, None, :, None] >= pb[:, None, None, :]
         if window is not None:
-            mask &= (q_pos[:, None, None, :, None] - pb[:, None, None, None, :]) < window
+            mask &= (qp[:, None, :, None] - pb[:, None, None, :]) < window
         s = jnp.where(mask, s, _NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m - m_new)
         l_new = l * corr + jnp.sum(p, axis=-1)
-        pv = jnp.einsum("bhgqk,bkhd->bhgqd", p, vb.astype(jnp.float32))
+        pv = jnp.einsum("bhqk,bhkd->bhqd", p, vb.astype(jnp.float32))
         o_new = o * corr[..., None] + pv
         return (m_new, l_new, o_new), None
 
-    m0 = jnp.full((B, Hkv, G, Sq), _NEG, jnp.float32)
-    l0 = jnp.zeros((B, Hkv, G, Sq), jnp.float32)
-    o0 = jnp.zeros((B, Hkv, G, Sq, D), jnp.float32)
-    (m, l, o), _ = jax.lax.scan(
-        step, (m0, l0, o0),
-        (jnp.moveaxis(kc, 1, 0), jnp.moveaxis(vc, 1, 0), jnp.moveaxis(pc, 1, 0)))
+    m0 = jnp.full((B, Hkv, Sq * G), _NEG, jnp.float32)
+    l0 = jnp.zeros((B, Hkv, Sq * G), jnp.float32)
+    o0 = jnp.zeros((B, Hkv, Sq * G, D), jnp.float32)
+    (m, l, o), _ = jax.lax.scan(step, (m0, l0, o0), (kc, vc, pc))
     o = o / jnp.maximum(l, 1e-30)[..., None]
-    out = jnp.moveaxis(o, 3, 1).reshape(B, Sq, Hq, D)  # [B,Sq,Hkv,G,D]->merge
+    out = o.reshape(B, Hkv, Sq, G, D).transpose(0, 2, 1, 3, 4) \
+           .reshape(B, Sq, Hq, D)
     return out.astype(q.dtype)
 
 

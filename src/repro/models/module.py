@@ -37,33 +37,41 @@ def abstract_params(specs):
     return jax.tree.map(lambda s: s.abstract(), specs, is_leaf=_is_spec)
 
 
+def init_leaf(key, s: ParamSpec):
+    """Materialize one spec from its RNG stream."""
+    if s.init == "zeros":
+        return jnp.zeros(s.shape, s.dtype)
+    if s.init == "ones":
+        return jnp.ones(s.shape, s.dtype)
+    if s.init == "normal":
+        return (0.02 * jax.random.normal(key, s.shape)).astype(s.dtype)
+    if s.init == "embed":
+        return (1.0 * jax.random.normal(key, s.shape)).astype(s.dtype)
+    if s.init == "fan_in":
+        fan_in = s.shape[-2] if len(s.shape) >= 2 else s.shape[-1]
+        std = 1.0 / math.sqrt(fan_in)
+        return (std * jax.random.normal(key, s.shape)).astype(s.dtype)
+    if s.init.startswith("const:"):
+        return jnp.full(s.shape, float(s.init.split(":")[1]), s.dtype)
+    if s.init == "arange1":  # 1..n (Mamba A_log style)
+        n = int(np.prod(s.shape))
+        return jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)
+                       ).reshape(s.shape).astype(s.dtype)
+    raise ValueError(f"unknown init '{s.init}'")
+
+
+def leaf_keys(rng, specs):
+    """The per-leaf RNG streams `init_params` draws from, in flatten
+    order of the spec tree."""
+    return jax.random.split(rng, len(jax.tree.leaves(specs, is_leaf=_is_spec)))
+
+
 def init_params(rng, specs):
     """Materialize a spec tree with deterministic per-leaf RNG streams."""
     leaves, treedef = jax.tree.flatten(specs, is_leaf=_is_spec)
-    rngs = jax.random.split(rng, len(leaves))
-
-    def one(key, s: ParamSpec):
-        if s.init == "zeros":
-            return jnp.zeros(s.shape, s.dtype)
-        if s.init == "ones":
-            return jnp.ones(s.shape, s.dtype)
-        if s.init == "normal":
-            return (0.02 * jax.random.normal(key, s.shape)).astype(s.dtype)
-        if s.init == "embed":
-            return (1.0 * jax.random.normal(key, s.shape)).astype(s.dtype)
-        if s.init == "fan_in":
-            fan_in = s.shape[-2] if len(s.shape) >= 2 else s.shape[-1]
-            std = 1.0 / math.sqrt(fan_in)
-            return (std * jax.random.normal(key, s.shape)).astype(s.dtype)
-        if s.init.startswith("const:"):
-            return jnp.full(s.shape, float(s.init.split(":")[1]), s.dtype)
-        if s.init == "arange1":  # 1..n (Mamba A_log style)
-            n = int(np.prod(s.shape))
-            return jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)
-                           ).reshape(s.shape).astype(s.dtype)
-        raise ValueError(f"unknown init '{s.init}'")
-
-    return jax.tree.unflatten(treedef, [one(k, s) for k, s in zip(rngs, leaves)])
+    keys = leaf_keys(rng, specs)
+    return jax.tree.unflatten(treedef,
+                              [init_leaf(k, s) for k, s in zip(keys, leaves)])
 
 
 def param_count(specs) -> int:

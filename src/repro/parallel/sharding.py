@@ -20,11 +20,12 @@ Rule highlights (1000+-chip posture):
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # logical axis -> mesh axis (or tuple of mesh axes). None = replicate.
 DEFAULT_RULES: dict = {
@@ -134,33 +135,37 @@ def mesh_axis_size(logical: str, mesh: Mesh,
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """Fully-manual shard_map, tolerant of the jax API move.
-
-    New jax exposes `jax.shard_map(axis_names=..., check_vma=...)`; older
-    releases only have `jax.experimental.shard_map.shard_map`.  We always
-    go fully manual (every mesh axis): partial-manual (`auto=...`) trips
-    XLA partitioner check-failures on older jaxlibs.  Used by the
+    """Fully-manual shard_map over every mesh axis.  Used by the
     compressed train step (train/step.py) and the sharded serving engine
     (serve/engine.py)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, axis_names=set(mesh.axis_names),
-                  in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
+    return jax.shard_map(f, mesh=mesh, axis_names=set(mesh.axis_names),
+                         in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
+
+
+@contextlib.contextmanager
+def use_mesh(mesh: Mesh):
+    """Run jitted programs on `mesh`, with its axes visible to `constrain`.
+
+    (`jax.set_mesh` does both too, but in JAX 0.9 it also maps every jit
+    output's sharding back onto the mesh, which fails for some shardings
+    the partitioner picks.)"""
+    with mesh, jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        yield
 
 
 def constrain(x, logical_axes, mesh: Optional[Mesh] = None,
               rules: ShardingRules = ShardingRules()):
     """with_sharding_constraint by logical axes; no-op without a mesh.
 
-    Axes that are Manual in the current context (inside a shard_map) are
-    dropped from the spec — the surrounding shard_map already owns them."""
-    mesh = mesh or _current_mesh()
-    if mesh is None or mesh.empty or len(mesh.devices.flatten()) == 1:
+    The mesh defaults to the abstract mesh `use_mesh` installed.  Axes that are
+    Manual in the current context (inside a shard_map) are dropped from
+    the spec — the surrounding shard_map already owns them."""
+    mesh = mesh or jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
         return x
-    manual = _manual_axes()
+    manual = {name for name, t in zip(mesh.axis_names, mesh.axis_types)
+              if t == AxisType.Manual}
     spec = spec_for(x.shape, logical_axes, mesh, rules)
     if manual:
         cleaned = []
@@ -176,34 +181,6 @@ def constrain(x, logical_axes, mesh: Optional[Mesh] = None,
         if all(e is None for e in spec):
             return x
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-
-
-def _manual_axes():
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is None or am.empty:
-            return set()
-        return {name for name, t in zip(am.axis_names, am.axis_types)
-                if "Manual" in str(t)}
-    except AttributeError:
-        # older jax: no abstract-mesh introspection, but shard_map binds its
-        # axes as named axes — anything in the axis env is manual here.
-        try:
-            from jax._src import core
-            return set(core.get_axis_env().axis_sizes)
-        except Exception:
-            return set()
-    except Exception:
-        return set()
-
-
-def _current_mesh() -> Optional[Mesh]:
-    try:
-        env = jax._src.mesh.thread_resources.env  # jax's implicit mesh ctx
-        m = env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
 
 
 def tree_specs(spec_tree, mesh, rules=ShardingRules()):

@@ -611,8 +611,10 @@ class ServingEngine:
         self._fork_fn = jax.jit(sm(
             lambda kv, d, s: fork_page(kv, d, s, shard=sctx),
             mesh, in_specs=(pool, rep, rep), out_specs=pool))
-        # place the freshly-zeroed cache on the mesh up front so the first
-        # entry-point call doesn't implicitly reshard host-resident arrays
+        # place params and the freshly-zeroed cache on the mesh up front:
+        # params left on one device would be copied to every other device
+        # on every entry-point call (a transient weight replica per call)
+        self.params = jax.device_put(self.params, NamedSharding(mesh, rep))
         self.cache = {
             name: jax.device_put(leaf, NamedSharding(mesh, cspec[name]))
             for name, leaf in self.cache.items()}

@@ -14,6 +14,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from repro import configs
 from repro.core import posit
 from repro.core.formats import P8_2, P16_1, P16_2
 from repro.kernels import autotune, ops, posit_codec
@@ -212,7 +213,7 @@ def test_ops_paged_prime_t_falls_back_to_untuned_default(scratch_cache):
 
 def test_ops_decode_sample_prime_vocab_falls_back(scratch_cache):
     """Regression companion for the fused decode epilogue: a cached
-    v_block over a prime vocab drops to the whole-vocab untuned default
+    v_block over a prime vocab runs with a masked partial last tile
     instead of a 1-column grid — sampled tokens bitwise unchanged."""
     rng = np.random.default_rng(32)
     B, D, V = 3, 16, 47  # V=47 prime
@@ -262,8 +263,7 @@ def test_ops_paged_rejects_nondividing_t_block(scratch_cache):
 def test_ops_decode_sample_resolves_v_block(scratch_cache):
     """Cached vocab tiles for the fused decode epilogue resolve at
     dispatch and never change the sampled token: the 0 sentinel collapses
-    the vocab grid, and a non-dividing tile degrades to the largest
-    divisor below it."""
+    the vocab grid, and a non-dividing tile masks its partial last tile."""
     rng = np.random.default_rng(21)
     B, D, V = 3, 16, 48
     x = jnp.asarray(rng.normal(0, 1, (B, D)), jnp.float32)
@@ -273,7 +273,7 @@ def test_ops_decode_sample_resolves_v_block(scratch_cache):
     autotune.reset_cache(autotune.AutotuneCache())  # empty: all misses
     want = ops.decode_sample(x, w, noise, temp, plan="fused", fmt_w=P16_2,
                              top_k=5)
-    for vb in (0, 32):  # whole-vocab sentinel; 32 degrades to 24
+    for vb in (0, 32):  # whole-vocab sentinel; 32 leaves a 16-wide tail
         c = autotune.AutotuneCache()
         c.put("decode_sample", (B, D, V), {"v_block": vb}, fmts=(P16_2,))
         autotune.reset_cache(c)
@@ -281,6 +281,43 @@ def test_ops_decode_sample_resolves_v_block(scratch_cache):
                                 fmt_w=P16_2, top_k=5)
         assert c.hits.get("decode_sample", 0) >= 1
         assert bool(jnp.all(got == want))
+
+
+@pytest.mark.parametrize("transpose", [False, True], ids=["dv", "tied_vd"])
+@pytest.mark.parametrize("top_k", [0, 5], ids=["greedy", "top_k"])
+def test_decode_sample_masked_last_tile(top_k, transpose):
+    """A vocab tile that does not divide V leaves a partial last tile; its
+    columns past V are masked, so the sampled tokens are bitwise those of
+    the whole-vocab grid."""
+    from repro.kernels import paged_attention as pa
+    rng = np.random.default_rng(33)
+    B, D, V = 3, 16, 47  # 3 tiles of 16, the last one 15 wide
+    x = jnp.asarray(rng.normal(0, 1, (B, D)), jnp.float32)
+    w = posit.pack(jnp.asarray(rng.normal(0, 1, (D, V)), jnp.float32), P16_2)
+    if transpose:
+        w = w.T
+    noise = jnp.asarray(rng.gumbel(size=(B, V)), jnp.float32)
+    kw = dict(plan="fused", fmt_w=P16_2, transpose=transpose,
+              greedy=top_k == 0, top_k=top_k, interpret=True)
+    want = pa.decode_sample(x, w, noise, jnp.float32(0.7), v_block=V, **kw)
+    got = pa.decode_sample(x, w, noise, jnp.float32(0.7), v_block=16, **kw)
+    assert bool(jnp.all(got == want))
+
+
+@pytest.mark.parametrize("name", configs.ARCH_NAMES)
+def test_default_v_block_fits_vmem(name):
+    """The untuned vocab tile of every published head fits the VMEM tile
+    budget (or is the narrowest lane-aligned tile), and never spans the
+    whole vocabulary of a head too large for one tile."""
+    from repro.kernels import paged_attention as pa
+    cfg = configs.get(name)
+    V, D = cfg.vocab_size, cfg.d_model
+    vb = pa.default_v_block(V, D)
+    if V * D * 4 <= pa._V_TILE_BYTES:
+        assert vb == V
+    else:
+        assert vb < V and vb % 128 == 0
+        assert vb * D * 4 <= pa._V_TILE_BYTES or vb == 128
 
 
 def test_ops_prefill_resolves_launch_knobs(scratch_cache):

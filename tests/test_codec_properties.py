@@ -94,8 +94,10 @@ def test_decode_monotonic_jax(fmt, data):
 def test_encode_monotonic_jax(fmt, data):
     """encode is monotone in the float value (never reorders operands —
     what keeps fake_quant and fused rankings consistent)."""
-    x = data.draw(st.floats(-1e20, 1e20, allow_nan=False, width=32))
-    y = data.draw(st.floats(-1e20, 1e20, allow_nan=False, width=32))
+    # width=32 bounds must themselves be float32 values
+    lim = float(np.float32(1e20))
+    x = data.draw(st.floats(-lim, lim, allow_nan=False, width=32))
+    y = data.draw(st.floats(-lim, lim, allow_nan=False, width=32))
     if x > y:
         x, y = y, x
     cx, cy = (int(c) & fmt.mask for c in

@@ -43,8 +43,9 @@ def test_spec_for_on_8dev():
     out = _run("""
         import jax, numpy as np
         from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_host_mesh
         from repro.parallel import sharding
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh(data=2, model=4)
         # divisible: shard both
         s = sharding.spec_for((64, 128), ("embed", "heads"), mesh)
         assert s == P("data", "model"), s
@@ -67,6 +68,7 @@ def test_train_step_multidevice_matches_single():
     code = """
         import jax, numpy as np, jax.numpy as jnp
         from repro import configs
+        from repro.launch.mesh import make_host_mesh
         from repro.models.config import ShapeConfig
         from repro.models.module import ParamSpec, abstract_params
         from repro.optim import adamw, constant_schedule
@@ -89,14 +91,14 @@ def test_train_step_multidevice_matches_single():
                     state, m = ts(state, b)
                     losses.append(float(m["loss"]))
             else:
-                with mesh:
+                with sh.use_mesh(mesh):
                     for b in batches:
                         state, m = ts(state, b)
                         losses.append(float(m["loss"]))
             return losses
 
         l1 = run(None)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh(data=2, model=4)
         l2 = run(mesh)
         print("L1", l1)
         print("L2", l2)
@@ -111,22 +113,24 @@ def test_compressed_grad_allreduce_2pods():
     code = """
         import jax, numpy as np, jax.numpy as jnp
         from repro import configs
+        from repro.launch.mesh import make_host_mesh
         from repro.models.config import ShapeConfig
         from repro.optim import adamw, constant_schedule
         from repro.train import step as step_lib
         from repro.data import Pipeline
+        from repro.parallel import sharding as sh
 
         cfg = configs.get_smoke("minitron_8b").replace(dtype="float32")
         shape = ShapeConfig("t", 16, 8, "train")
         opt = adamw(constant_schedule(2e-3))
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_host_mesh(pod=2, data=2, model=2)
         ts = step_lib.make_train_step_compressed(cfg, opt, mesh)
         state = step_lib.init_state(jax.random.key(0), cfg, opt)
         err = ts.init_err(state.params)
         pipe = Pipeline(cfg, shape)
         losses = []
         carry = (state, err)
-        with mesh:
+        with sh.use_mesh(mesh):
             tsj = jax.jit(ts)
             for s in range(8):
                 carry, m = tsj(carry, jax.tree.map(jnp.asarray, pipe.batch_at(s)))
@@ -149,8 +153,9 @@ def test_dryrun_cell_small_mesh():
         import jax
         from repro import configs
         from repro.launch import dryrun
+        from repro.launch.mesh import make_host_mesh
         from repro.models.config import ShapeConfig
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_host_mesh(pod=2, data=2, model=2)
         cfg = configs.get_smoke("qwen3_moe_235b")
         for kind, shape in [("train", ShapeConfig("t", 64, 8, "train")),
                             ("prefill", ShapeConfig("p", 64, 8, "prefill")),
@@ -171,14 +176,15 @@ def test_elastic_restore_across_meshes():
         import tempfile, jax, numpy as np, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import CheckpointManager
+        from repro.launch.mesh import make_host_mesh
 
         tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
         with tempfile.TemporaryDirectory() as d:
             mgr = CheckpointManager(d)
-            mesh1 = jax.make_mesh((2, 4), ("data", "model"))
+            mesh1 = make_host_mesh(data=2, model=4)
             w1 = jax.device_put(tree["w"], NamedSharding(mesh1, P("data", "model")))
             mgr.save(1, {"w": w1})
-            mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+            mesh2 = make_host_mesh(data=4, model=2)
             sh2 = {"w": NamedSharding(mesh2, P("model", "data"))}
             got = mgr.restore(1, {"w": jax.ShapeDtypeStruct((8, 8), jnp.float32)}, sh2)
             assert (np.asarray(got["w"]) == np.asarray(tree["w"])).all()

@@ -82,3 +82,32 @@ def test_pack_params_round_trip_types(name):
     from repro.core import posit
     want = posit.quantize(jnp.asarray(master, jnp.float32), P16_2)
     assert (np.asarray(leaf) == np.asarray(want)).all()
+
+
+@pytest.mark.parametrize("name", configs.ARCH_NAMES)
+def test_init_packed_equals_packed_init(name):
+    """init_packed (leaf-at-a-time, never the whole float tree) builds
+    bitwise the tree pack_params makes from the float init."""
+    cfg = configs.get_smoke(name).replace(quant=QuantPolicy(weights=P16_2))
+    want = api.pack_params(api.init(jax.random.key(3), cfg), cfg)
+    got = api.init_packed(jax.random.key(3), cfg)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_init_packed_places_each_leaf():
+    """With a sharding, every leaf comes out placed on it (replicated over
+    a serving mesh), with the same values as the unplaced build."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.launch.mesh import make_serving_mesh
+    cfg = configs.get_smoke("gemma3_4b").replace(
+        quant=QuantPolicy(weights=P16_2))
+    rep = NamedSharding(make_serving_mesh(1), PartitionSpec())
+    want = api.init_packed(jax.random.key(3), cfg)
+    got = api.init_packed(jax.random.key(3), cfg, sharding=rep)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.sharding == rep
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -342,6 +342,7 @@ def test_mesh_engine_per_device_budget_guard_and_validation():
     out = _run("""
         import jax, numpy as np
         from repro import configs
+        from repro.launch.mesh import make_host_mesh
         from repro.models import api
         from repro.serve import Request, ServingEngine
         from repro.launch.mesh import make_serving_mesh
@@ -368,7 +369,7 @@ def test_mesh_engine_per_device_budget_guard_and_validation():
                           max_new_tokens=ps))
 
         # a >1 mesh axis kv_pages does not shard over is rejected
-        mesh2 = jax.make_mesh((2, 1), ("data", "model"))
+        mesh2 = make_host_mesh(data=2, model=1)
         try:
             ServingEngine(cfg, params, batch_slots=1, max_seq=4 * ps,
                           mesh=mesh2)

@@ -195,3 +195,56 @@ def test_hwmodel_trends():
     assert r.delay_ns / max(r.stage_delay_ns) > 3.0
     # decoders (S1) dominate area (paper §IV-B)
     assert r.stage_area_um2[0] == max(r.stage_area_um2)
+
+
+def test_peaks_keyed_by_device_kind():
+    """A known chip gets its published peaks; an unknown one raises rather
+    than inherit the v5e's."""
+    from repro.launch.mesh import V5E, peaks
+    hw = peaks(V5E)
+    assert hw["peak_flops_bf16"] == 197e12 and hw["hbm_bw"] == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")
+
+
+@pytest.mark.parametrize("kind", ["TPU v5 lite", "TPU v9 imaginary"])
+def test_oracle_prices_the_attached_chip(kind, monkeypatch):
+    """On a TPU the autotune oracle takes the attached chip's own peaks,
+    by its device kind; a kind without published peaks raises."""
+    from repro.kernels import autotune, ops
+    from repro.launch.mesh import PEAKS
+
+    class Chip:
+        device_kind = kind
+
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    monkeypatch.setattr(jax, "devices", lambda *a: [Chip()])
+    if kind in PEAKS:
+        assert autotune.device_peaks() is PEAKS[kind]
+    else:
+        with pytest.raises(KeyError, match="no published peaks"):
+            autotune.oracle_cost("decode_sample", (4, 64, 512),
+                                 {"v_block": 128})
+
+
+@pytest.mark.parametrize("env_set", [True, False], ids=["env", "default"])
+def test_compile_cache_dir(env_set, monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache goes to
+    the fixed <repo>/.jax_cache."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env_set:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert got == os.path.join(repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
