@@ -241,6 +241,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, window,
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
+        name="paged_attention",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
@@ -467,6 +468,7 @@ def decode_sample(x, w, noise=None, temperature=None, *, plan: str = "fused",
         out_shape=jax.ShapeDtypeStruct((1, B), jnp.int32),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="decode_sample",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),

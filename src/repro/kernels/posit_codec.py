@@ -70,6 +70,7 @@ def decode(codes, fmt: PositFormat, block_r=None, block_c=None,
         out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((R, C), jnp.float32),
         interpret=interpret,
+        name="decode",
     )(x2)
     return out.reshape(orig_shape)
 
@@ -94,5 +95,6 @@ def encode(values, fmt: PositFormat, block_r=None, block_c=None,
         out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((R, C), out_dtype),
         interpret=interpret,
+        name="encode",
     )(x2)
     return out.reshape(orig_shape)

@@ -385,6 +385,7 @@ def prefill_attention_paged(q, k, v, k_pages, v_pages, block_tables, starts,
         out_shape=out_shapes,
         input_output_aliases=aliases,
         interpret=interpret,
+        name="prefill_attention_paged",
         compiler_params=pltpu.CompilerParams(**cp_kwargs),
     )(block_tables.astype(jnp.int32), starts.astype(jnp.int32),
       window.astype(jnp.int32), page_ok.astype(jnp.int32),

@@ -104,8 +104,13 @@ def main():
     print(f"[serve] {len(done)} requests, {total_new} tokens in {dt:.2f}s "
           f"({total_new/dt:.1f} tok/s) kv dtype="
           f"{'posit' if cfg.quant.kv_cache else cfg.dtype} cache={layout}")
+    s = engine.execution_summary()
+    print(f"[serve] scheduling: {s['admissions']} admissions, queue wait "
+          f"{_ms(s['queue_wait_mean_ms'])}, admit to first token "
+          f"{_ms(s['admit_to_first_token_mean_ms'])} (means); prefill row "
+          f"use {_pct(s['prefill_row_use'])}, decode slot use "
+          f"{_pct(s['decode_slot_use'])}")
     if args.speculate:
-        s = engine.execution_summary()
         print(f"[serve] speculation: k={s['speculate_k']} "
               f"rounds={s['speculation_rounds']} "
               f"accept_rate={s['speculation_accept_rate']:.3f} "
@@ -115,6 +120,14 @@ def main():
         per = engine.allocator.pages_per_shard - 1
         print("[serve] per-device page occupancy: "
               + " ".join(f"d{i}={u}/{per}" for i, u in enumerate(occ)))
+
+
+def _ms(v):
+    return "n/a" if v is None else f"{v:.1f} ms"
+
+
+def _pct(v):
+    return "n/a" if v is None else f"{100 * v:.1f}%"
 
 
 if __name__ == "__main__":

@@ -68,11 +68,34 @@ through the fused Pallas kernels (`execution='fused'`), including grouped
 MoE expert stacks and activation-coded policies — see
 `execution_summary()` for the datapath and the kv_bytes/metadata_bytes
 storage split an engine is actually running.
+
+**Counters** (`engine.stats`, plain ints and floats, host values only):
+prefix sharing (`pages_shared`, `shared_admissions`, `cow_forks`);
+prefill (`prefill_chunks`, `prefill_device_programs`,
+`prefill_batch_sizes` {live rows: programs}, `prefill_live_tokens` /
+`prefill_row_tokens`: chunk tokens of live rows / rows x chunk size of
+every chunk program); decode (`decode_steps`, `decode_device_programs`,
+`decode_rows`: live rows summed over decode steps); scheduling
+(`admissions`, `queue_wait_s`: summed (re)queueing -> admission,
+`first_tokens`, `admit_to_first_token_s`: summed admission -> first token
+sampled, both on `time.perf_counter`; `preemptions`); speculation
+(`spec_rounds`, `spec_draft_tokens`, `spec_accepted_tokens`,
+`spec_committed_tokens`).  `execution_summary()` turns the scheduling
+ones into means and use ratios.
+
+**Spans** (serve/tracing.py, only while a profiler collects): `serve.step`
+around each `step()`, holding `serve.admit`, `serve.prefill_chunk` (host
+preparation and dispatch of one chunk program), `serve.first_token_wait`,
+then `serve.refresh_meta`, `serve.decode_dispatch`, `serve.token_wait`
+(the blocking token read) and `serve.retire`; a speculative round nests
+the same under `serve.spec_round`.  Every jitted program has a stable
+name (`jit_serve_decode_sample`, `jit_serve_prefill_chunk_batched`, ...).
 """
 from __future__ import annotations
 
 import dataclasses
 import hashlib
+import time
 from typing import Dict, List, Optional
 
 import jax
@@ -85,6 +108,7 @@ from repro.models.config import ModelConfig
 from repro.models.paged import (PagedLayout, PageShard, fork_page,
                                 fused_prefill_span_ok)
 from repro.parallel import sharding
+from repro.serve.tracing import span
 
 
 @dataclasses.dataclass
@@ -95,6 +119,12 @@ class Request:
     eos_id: Optional[int] = None
     seed: Optional[int] = None   # sampling stream (defaults to rid)
     out_tokens: Optional[list] = None
+    # time.perf_counter() at the last (re)queueing and admission: the
+    # engine's queue-wait and admit-to-first-token counters
+    queued_at: Optional[float] = dataclasses.field(
+        default=None, repr=False, compare=False)
+    admitted_at: Optional[float] = dataclasses.field(
+        default=None, repr=False, compare=False)
 
 
 class PageAllocator:
@@ -216,6 +246,13 @@ class PageAllocator:
         return recycled
 
 
+def _jit_named(name: str, fn):
+    """jax.jit under a stable program name: the device trace's "XLA
+    Modules" line reads `jit_<name>` for it, not `jit__lambda`."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
 def _build_sampler(greedy: bool, top_k: int):
     """jit'd token sampler: logits [B, V] + per-row keys -> [B] int32."""
 
@@ -228,7 +265,7 @@ def _build_sampler(greedy: bool, top_k: int):
             l = jnp.where(l >= kth, l, -1e30)
         return jax.vmap(jax.random.categorical)(keys, l).astype(jnp.int32)
 
-    return jax.jit(sample)
+    return _jit_named("serve_sample", sample)
 
 
 _FREE, _PREFILL, _DECODE = 0, 1, 2
@@ -385,26 +422,31 @@ class ServingEngine:
             self._install_sharded_fns()
         else:
             self._page_shard = None
-            self._decode = jax.jit(
-                lambda p, t, c: api.decode_step(p, t, c, cfg))
+            self._decode = _jit_named(
+                "serve_decode", lambda p, t, c: api.decode_step(p, t, c, cfg))
             if self.fused_decode:
                 gd, tk, V = greedy, self.top_k, cfg.vocab_size
-                self._decode_sample = jax.jit(
+                self._decode_sample = _jit_named(
+                    "serve_decode_sample",
                     lambda p, t, c, keys, temp: api.decode_and_sample(
                         p, t, c, cfg,
                         None if gd else api.sample_noise(keys, V),
                         temp, greedy=gd, top_k=tk))
-            self._chunk = jax.jit(
+            self._chunk = _jit_named(
+                "serve_prefill_chunk",
                 lambda p, t, c, s: api.prefill_chunk(p, t, c, s, cfg))
-            self._chunk_batched = jax.jit(
+            self._chunk_batched = _jit_named(
+                "serve_prefill_chunk_batched",
                 lambda p, t, c, a: api.prefill_chunk_batched(p, t, c, a,
                                                              cfg))
             # COW page duplication; dst/src are traced so one compile
             # covers every fork
-            self._fork_fn = jax.jit(fork_page)
+            self._fork_fn = _jit_named(
+                "serve_fork_page", lambda kv, d, s: fork_page(kv, d, s))
         # whole-prompt prefill, kept as a reference/debug probe only — the
         # serving path never calls it (chunked prefill replaces it)
-        self._prefill = jax.jit(
+        self._prefill = _jit_named(
+            "serve_prefill",
             lambda p, b: api.prefill(p, b, cfg, max_seq=max_seq))
         self._sampler = _build_sampler(greedy, self.top_k)
         self._base_key = jax.random.key(base_seed)
@@ -442,7 +484,11 @@ class ServingEngine:
                       "decode_steps": 0, "decode_device_programs": 0,
                       "preemptions": 0, "spec_rounds": 0,
                       "spec_draft_tokens": 0, "spec_accepted_tokens": 0,
-                      "spec_committed_tokens": 0}
+                      "spec_committed_tokens": 0,
+                      "admissions": 0, "queue_wait_s": 0.0,
+                      "first_tokens": 0, "admit_to_first_token_s": 0.0,
+                      "decode_rows": 0, "prefill_live_tokens": 0,
+                      "prefill_row_tokens": 0}
 
         # batch-dim index per cache leaf, for restoring rows of slots that
         # were mid-prefill during a decode call (page pools have no batch
@@ -493,10 +539,12 @@ class ServingEngine:
             draft_cfg = dataclasses.replace(cfg, quant=dq)
             self.draft_params = params if draft_params is None else \
                 draft_params
-            self._draft_decode = jax.jit(
+            self._draft_decode = _jit_named(
+                "serve_draft_decode",
                 lambda p, t, c: api.decode_step(p, t, c, draft_cfg))
             gd, tk, V = greedy, self.top_k, cfg.vocab_size
-            self._verify = jax.jit(
+            self._verify = _jit_named(
+                "serve_verify",
                 lambda p, t, c, keys, temp: api.decode_verify(
                     p, t, c, cfg,
                     None if gd else api.sample_noise(keys, V),
@@ -570,7 +618,7 @@ class ServingEngine:
         rep = P()
         prep = jax.tree.map(lambda _: rep, self.params)
         sm, mesh = sharding.shard_map, self.mesh
-        self._decode = jax.jit(sm(
+        self._decode = _jit_named("serve_decode", sm(
             lambda p, t, c: api.decode_step(p, t, c, cfg, shard=sctx),
             mesh, in_specs=(prep, rep, cspec), out_specs=(rep, cspec)))
         if self.fused_decode:
@@ -582,7 +630,8 @@ class ServingEngine:
                         shard=sctx),
                     mesh, in_specs=(prep, rep, cspec, rep),
                     out_specs=(rep, cspec))
-                self._decode_sample = jax.jit(
+                self._decode_sample = _jit_named(
+                    "serve_decode_sample",
                     lambda p, t, c, keys, temp: inner(p, t, c, temp))
             else:
                 # gumbel noise is drawn once outside the shard_map (it only
@@ -594,21 +643,22 @@ class ServingEngine:
                         shard=sctx),
                     mesh, in_specs=(prep, rep, cspec, rep, rep),
                     out_specs=(rep, cspec))
-                self._decode_sample = jax.jit(
+                self._decode_sample = _jit_named(
+                    "serve_decode_sample",
                     lambda p, t, c, keys, temp: inner(
                         p, t, c, api.sample_noise(keys, V), temp))
-        self._chunk = jax.jit(sm(
+        self._chunk = _jit_named("serve_prefill_chunk", sm(
             lambda p, t, c, s: api.prefill_chunk(p, t, c, s, cfg,
                                                  shard=sctx),
             mesh, in_specs=(prep, rep, cspec, rep),
             out_specs=(rep, cspec)))
-        self._chunk_batched = jax.jit(sm(
+        self._chunk_batched = _jit_named("serve_prefill_chunk_batched", sm(
             lambda p, t, c, a: api.prefill_chunk_batched(p, t, c, a, cfg,
                                                          shard=sctx),
             mesh, in_specs=(prep, rep, cspec, rep),
             out_specs=(rep, cspec)))
         pool = cspec["k"]
-        self._fork_fn = jax.jit(sm(
+        self._fork_fn = _jit_named("serve_fork_page", sm(
             lambda kv, d, s: fork_page(kv, d, s, shard=sctx),
             mesh, in_specs=(pool, rep, rep), out_specs=pool))
         # place params and the freshly-zeroed cache on the mesh up front:
@@ -734,9 +784,17 @@ class ServingEngine:
         return sum(rc - 1 for rc in self.allocator._refs.values())
 
     def execution_summary(self) -> dict:
-        """Which datapath this engine serves on, plus its storage terms."""
+        """Which datapath this engine serves on, its storage terms, and the
+        scheduling counters as means and use ratios (None before the first
+        event): queue wait and admit-to-first-token in ms, the share of
+        chunk-program tokens that carry a prompt, and the share of
+        decode-step rows that carry a live request."""
         q = self.cfg.quant
         kv = self.kv_cache_summary()
+        st = self.stats
+
+        def ratio(num, den, scale=1.0):
+            return scale * st[num] / den if den else None
         return {
             "execution": q.execution,
             "weights": str(q.weights) if q.weights else None,
@@ -775,6 +833,15 @@ class ServingEngine:
                 self.stats["spec_accepted_tokens"]
                 / self.stats["spec_draft_tokens"]
                 if self.stats["spec_draft_tokens"] else None),
+            "admissions": st["admissions"],
+            "queue_wait_mean_ms": ratio("queue_wait_s", st["admissions"],
+                                        1e3),
+            "admit_to_first_token_mean_ms": ratio(
+                "admit_to_first_token_s", st["first_tokens"], 1e3),
+            "prefill_row_use": ratio("prefill_live_tokens",
+                                     st["prefill_row_tokens"]),
+            "decode_slot_use": ratio("decode_rows",
+                                     st["decode_steps"] * self.B),
         }
 
     # ------------------------------------------------------------------
@@ -806,6 +873,7 @@ class ServingEngine:
                 f"but the pool only has {self.allocator.capacity}{budgets}; "
                 f"raise n_pages or shorten prompt/max_new_tokens")
         req.out_tokens = []
+        req.queued_at = time.perf_counter()
         self.queue.append(req)
 
     def _pages_needed(self, req: Request) -> int:
@@ -1180,7 +1248,15 @@ class ServingEngine:
         shared prefix pages are mapped by reference and a copy-on-write
         fork page is pre-reserved, so an admitted request never allocates
         mid-flight.  Returns True if any request was admitted."""
-        admitted = False
+        with span("serve.admit") as sp:
+            rids = self._admit_queued()
+            if sp and rids:
+                sp.set_metadata(rid=" ".join(map(str, rids)))
+        return bool(rids)
+
+    def _admit_queued(self) -> List[int]:
+        """_admit's work; returns the rids admitted, in slot order."""
+        admitted: List[int] = []
         for slot in range(self.B):
             if self.slot_phase[slot] != _FREE or not self.queue:
                 continue
@@ -1232,6 +1308,9 @@ class ServingEngine:
             self.queue.pop(qi)
             if self.paged:
                 self._prune_holds()
+            req.admitted_at = time.perf_counter()
+            self.stats["admissions"] += 1
+            self.stats["queue_wait_s"] += req.admitted_at - req.queued_at
             self.slot_req[slot] = req
             self.slot_phase[slot] = _PREFILL
             self.slot_cursor[slot] = n_shared
@@ -1241,7 +1320,7 @@ class ServingEngine:
             self._reset_slot_state(slot)
             if state is not None:
                 self._restore_state(slot, state)
-            admitted = True
+            admitted.append(req.rid)
         return admitted
 
     def _release(self, slot: int):
@@ -1285,6 +1364,7 @@ class ServingEngine:
             return None
         req = self.slot_req[slot]
         req.out_tokens = []
+        req.queued_at = time.perf_counter()
         self.queue.insert(0, req)
         self._release(slot)
         self.stats["preemptions"] += 1
@@ -1311,6 +1391,9 @@ class ServingEngine:
         prefill (eos / single-token budget) or move to decode."""
         req = self.slot_req[slot]
         req.out_tokens.append(tok)
+        self.stats["first_tokens"] += 1
+        self.stats["admit_to_first_token_s"] += \
+            time.perf_counter() - req.admitted_at
         if req.max_new_tokens <= 1 or (
                 req.eos_id is not None and tok == req.eos_id):
             self._retire(slot)  # finished at prefill: reclaim pages now
@@ -1337,23 +1420,34 @@ class ServingEngine:
         prompt = np.asarray(req.prompt, np.int32)
         lo = int(self.slot_cursor[slot])
         size = self._next_chunk(slot)
-        if self.paged:
-            self._ensure_writable(slot, lo, lo + size)
-        tokens = jnp.asarray(prompt[None, lo:lo + size])
-        cache = self._refresh_meta(self.cache)
-        logits, self.cache = self._chunk(self.params, tokens, cache,
-                                         jnp.int32(slot))
+        with span("serve.prefill_chunk") as sp:
+            if sp:
+                sp.set_metadata(size=size, rows=1)
+            if self.paged:
+                self._ensure_writable(slot, lo, lo + size)
+            tokens = jnp.asarray(prompt[None, lo:lo + size])
+            cache = self._refresh_meta(self.cache)
+            logits, self.cache = self._chunk(self.params, tokens, cache,
+                                             jnp.int32(slot))
+            self._count_chunk(1, 1, size)
+            self.slot_cursor[slot] += size
+            self.lengths[slot] += size
+            self._register_pages(slot)
+        if int(self.slot_cursor[slot]) >= len(prompt):
+            with span("serve.first_token_wait"):
+                tok = int(self._sample(logits[:, -1], [slot])[0])
+            self._finish_prompt(slot, tok)
+
+    def _count_chunk(self, live_rows: int, rows: int, size: int):
+        """Counters of one chunk program of `rows` x `size` tokens, of which
+        `live_rows` rows carry a prompt."""
         sizes = self.stats["prefill_batch_sizes"]
-        sizes[1] = sizes.get(1, 0) + 1
+        sizes[live_rows] = sizes.get(live_rows, 0) + 1
         self.stats["prefill_chunks"] += 1
         self.stats["prefill_device_programs"] += \
             self._prefill_programs_per_chunk(size)
-        self.slot_cursor[slot] += size
-        self.lengths[slot] += size
-        self._register_pages(slot)
-        if int(self.slot_cursor[slot]) >= len(prompt):
-            tok = int(self._sample(logits[:, -1], [slot])[0])
-            self._finish_prompt(slot, tok)
+        self.stats["prefill_live_tokens"] += live_rows * size
+        self.stats["prefill_row_tokens"] += rows * size
 
     def _run_chunk_group(self, slots: List[int], size: int):
         """Advance every slot in `slots` by one chunk of `size` tokens in
@@ -1361,27 +1455,27 @@ class ServingEngine:
         Non-group rows are masked: their length/block-table metadata is
         zeroed (paged writes land on the trash page) and the model reverts
         their batch-dim state rows against the input cache."""
-        tokens = np.zeros((self.B, size), np.int32)
-        for s in slots:
-            lo = int(self.slot_cursor[s])
-            tokens[s] = np.asarray(self.slot_req[s].prompt,
-                                   np.int32)[lo:lo + size]
-            if self.paged:
-                self._ensure_writable(s, lo, lo + size)
-        active = np.zeros(self.B, bool)
-        active[slots] = True
-        cache_in = self._refresh_meta(self.cache, active)
-        logits, self.cache = self._chunk_batched(
-            self.params, jnp.asarray(tokens), cache_in, jnp.asarray(active))
-        sizes = self.stats["prefill_batch_sizes"]
-        sizes[len(slots)] = sizes.get(len(slots), 0) + 1
-        self.stats["prefill_chunks"] += 1
-        self.stats["prefill_device_programs"] += \
-            self._prefill_programs_per_chunk(size)
-        for s in slots:
-            self.slot_cursor[s] += size
-            self.lengths[s] += size
-            self._register_pages(s)
+        with span("serve.prefill_chunk") as sp:
+            if sp:
+                sp.set_metadata(size=size, rows=len(slots))
+            tokens = np.zeros((self.B, size), np.int32)
+            for s in slots:
+                lo = int(self.slot_cursor[s])
+                tokens[s] = np.asarray(self.slot_req[s].prompt,
+                                       np.int32)[lo:lo + size]
+                if self.paged:
+                    self._ensure_writable(s, lo, lo + size)
+            active = np.zeros(self.B, bool)
+            active[slots] = True
+            cache_in = self._refresh_meta(self.cache, active)
+            logits, self.cache = self._chunk_batched(
+                self.params, jnp.asarray(tokens), cache_in,
+                jnp.asarray(active))
+            self._count_chunk(len(slots), self.B, size)
+            for s in slots:
+                self.slot_cursor[s] += size
+                self.lengths[s] += size
+                self._register_pages(s)
         done = [s for s in slots if int(self.slot_cursor[s])
                 >= len(self.slot_req[s].prompt)]
         if done:
@@ -1389,7 +1483,8 @@ class ServingEngine:
             # rows of unfinished slots draw from dummy keys, discarded
             live = np.zeros(self.B, bool)
             live[done] = True
-            toks = self._sample(logits, list(range(self.B)), live=live)
+            with span("serve.first_token_wait"):
+                toks = self._sample(logits, list(range(self.B)), live=live)
             for s in done:
                 self._finish_prompt(s, int(toks[s]))
 
@@ -1432,65 +1527,78 @@ class ServingEngine:
         """One engine iteration: admit/prefill, then one decode step for
         every decoding slot.  Returns False when the engine is idle: no
         slot is decoding and no prefill remains in flight."""
-        self._fill_slots()
-        decode_mask = self.slot_phase == _DECODE
-        if not decode_mask.any():
-            return bool((self.slot_phase == _PREFILL).any())
-        if self.speculate_k:
-            T = self._spec_span(decode_mask)
-            if T >= 2:
-                self._spec_round(decode_mask, T)
-                return True
-        if self.paged:
-            for s in np.nonzero(decode_mask)[0]:
-                pos = int(self.lengths[s])
-                self._ensure_writable(int(s), pos, pos + 1)
-        cache_in = self._refresh_meta(self.cache, decode_mask)
-        if self.fused_decode:
-            # one device program per decode step: attention + logits head +
-            # sampler fused; keys are built (and counters advanced) exactly
-            # as the decomposed path would before its sampler dispatch
-            keys = self._sample_keys(list(range(self.B)), live=decode_mask)
-            toks_all, new_cache = self._decode_sample(
-                self.params, jnp.asarray(self.next_token), cache_in, keys,
-                jnp.float32(self.temperature))
-        else:
-            logits, new_cache = self._decode(
-                self.params, jnp.asarray(self.next_token), cache_in)
-        self.stats["decode_steps"] += 1
-        self.stats["decode_device_programs"] += 1 if self.fused_decode else 2
-        if (self.slot_phase == _PREFILL).any():
-            # slots mid-prefill (interleaved mode) must not have their
-            # recurrent/dense state rows advanced by this decode call
-            mask = jnp.asarray(decode_mask)
-            for name, leaf in new_cache.items():
-                bdim = self._state_bdim.get(name)
-                if name in ("length", "block_table") or bdim is None:
-                    continue
-                shape = [1] * leaf.ndim
-                shape[bdim] = self.B
-                m = mask.reshape(shape)
-                new_cache[name] = jnp.where(m, leaf, self.cache[name])
-        self.cache = new_cache
+        with span("serve.step"):
+            self._fill_slots()
+            decode_mask = self.slot_phase == _DECODE
+            if not decode_mask.any():
+                return bool((self.slot_phase == _PREFILL).any())
+            if self.speculate_k:
+                T = self._spec_span(decode_mask)
+                if T >= 2:
+                    with span("serve.spec_round"):
+                        self._spec_round(decode_mask, T)
+                    return True
+            self._decode_round(decode_mask)
+            return True
+
+    def _decode_round(self, decode_mask):
+        """One decode step for every slot in decode_mask."""
+        with span("serve.refresh_meta"):
+            if self.paged:
+                for s in np.nonzero(decode_mask)[0]:
+                    pos = int(self.lengths[s])
+                    self._ensure_writable(int(s), pos, pos + 1)
+            cache_in = self._refresh_meta(self.cache, decode_mask)
+        with span("serve.decode_dispatch"):
+            if self.fused_decode:
+                # one device program per decode step: attention + logits
+                # head + sampler fused; keys are built (and counters
+                # advanced) exactly as the decomposed path would before its
+                # sampler dispatch
+                keys = self._sample_keys(list(range(self.B)),
+                                         live=decode_mask)
+                toks_all, new_cache = self._decode_sample(
+                    self.params, jnp.asarray(self.next_token), cache_in,
+                    keys, jnp.float32(self.temperature))
+            else:
+                logits, new_cache = self._decode(
+                    self.params, jnp.asarray(self.next_token), cache_in)
+            if (self.slot_phase == _PREFILL).any():
+                # slots mid-prefill (interleaved mode) must not have their
+                # recurrent/dense state rows advanced by this decode call
+                mask = jnp.asarray(decode_mask)
+                for name, leaf in new_cache.items():
+                    bdim = self._state_bdim.get(name)
+                    if name in ("length", "block_table") or bdim is None:
+                        continue
+                    shape = [1] * leaf.ndim
+                    shape[bdim] = self.B
+                    m = mask.reshape(shape)
+                    new_cache[name] = jnp.where(m, leaf, self.cache[name])
+            self.cache = new_cache
         # sample over the full fixed [B, V] batch (rows of non-decoding
         # slots draw from dummy keys and are discarded) so the jitted
         # sampler never retraces as slots retire
         slots = [s for s in range(self.B) if decode_mask[s]]
-        if self.fused_decode:
-            toks = np.asarray(toks_all, np.int32)[np.asarray(slots)]
-        else:
-            toks = self._sample(logits, list(range(self.B)),
-                                live=decode_mask)[np.asarray(slots)]
-        for tok, slot in zip(toks, slots):
-            req = self.slot_req[slot]
-            req.out_tokens.append(int(tok))
-            self.next_token[slot] = tok
-            self.lengths[slot] += 1
-            self.slot_remaining[slot] -= 1
-            if self.slot_remaining[slot] <= 0 or (
-                    req.eos_id is not None and int(tok) == req.eos_id):
-                self._retire(slot)
-        return True
+        self.stats["decode_steps"] += 1
+        self.stats["decode_device_programs"] += 1 if self.fused_decode else 2
+        self.stats["decode_rows"] += len(slots)
+        with span("serve.token_wait"):
+            if self.fused_decode:
+                toks = np.asarray(toks_all, np.int32)[np.asarray(slots)]
+            else:
+                toks = self._sample(logits, list(range(self.B)),
+                                    live=decode_mask)[np.asarray(slots)]
+        with span("serve.retire"):
+            for tok, slot in zip(toks, slots):
+                req = self.slot_req[slot]
+                req.out_tokens.append(int(tok))
+                self.next_token[slot] = tok
+                self.lengths[slot] += 1
+                self.slot_remaining[slot] -= 1
+                if self.slot_remaining[slot] <= 0 or (
+                        req.eos_id is not None and int(tok) == req.eos_id):
+                    self._retire(slot)
 
     # ------------------------------------------------------------------
     # speculative decoding
@@ -1537,76 +1645,90 @@ class ServingEngine:
         """
         slots = [int(s) for s in np.nonzero(decode_mask)[0]]
         base = {s: int(self._slot_sampled[s]) for s in slots}
-        for s in slots:
-            pos = int(self.lengths[s])
-            self._ensure_writable(s, pos, pos + T)
         inputs = np.zeros((self.B, T), np.int32)
         inputs[:, 0] = self.next_token
+        with span("serve.refresh_meta"):
+            for s in slots:
+                pos = int(self.lengths[s])
+                self._ensure_writable(s, pos, pos + T)
+            cache = self._refresh_meta(self.cache, decode_mask)
         # ---- draft: propose d_1 .. d_{T-1}.  d_j guesses the target's
         # j-th draw, so it samples with that draw's key (base + j - 1) —
         # a draft whose logits match the target bitwise accepts 100%.
         cur = jnp.asarray(self.next_token)
-        cache = self._refresh_meta(self.cache, decode_mask)
         pool = self.cache
         for j in range(1, T):
-            logits, pool = self._draft_decode(self.draft_params, cur, cache)
-            if self.greedy:
-                keys = self._dummy_keys
-            else:
-                keys = jnp.stack([
-                    jax.random.fold_in(self._slot_keys[s],
-                                       base[s] + j - 1)
-                    if decode_mask[s] else self._dummy_keys[0]
-                    for s in range(self.B)])
-            toks = np.asarray(
-                self._sampler(logits, keys, jnp.float32(self.temperature)),
-                np.int32)
+            with span("serve.decode_dispatch"):
+                logits, pool = self._draft_decode(self.draft_params, cur,
+                                                  cache)
+                if self.greedy:
+                    keys = self._dummy_keys
+                else:
+                    keys = jnp.stack([
+                        jax.random.fold_in(self._slot_keys[s],
+                                           base[s] + j - 1)
+                        if decode_mask[s] else self._dummy_keys[0]
+                        for s in range(self.B)])
+                toks = self._sampler(logits, keys,
+                                     jnp.float32(self.temperature))
+            with span("serve.token_wait"):
+                toks = np.asarray(toks, np.int32)
             inputs[:, j] = toks
             cur = jnp.asarray(toks)
             if j < T - 1:
-                drafted = self.lengths + np.where(
-                    decode_mask, j, 0).astype(np.int32)
-                cache = self._refresh_meta(pool, decode_mask,
-                                           lengths=drafted)
+                with span("serve.refresh_meta"):
+                    drafted = self.lengths + np.where(
+                        decode_mask, j, 0).astype(np.int32)
+                    cache = self._refresh_meta(pool, decode_mask,
+                                               lengths=drafted)
         # the draft's page writes are placeholders: the verify pass below
         # re-inserts every one of the T positions with the serve policy's
         # codes (per layer, before its attention reads them)
-        cache = self._refresh_meta(pool, decode_mask)
-        keys = self._spec_verify_keys(decode_mask, T, base)
-        toks_bt, self.cache = self._verify(
-            self.params, jnp.asarray(inputs), cache, keys,
-            jnp.float32(self.temperature))
-        toks = np.asarray(toks_bt, np.int32)
+        with span("serve.refresh_meta"):
+            cache = self._refresh_meta(pool, decode_mask)
+        with span("serve.decode_dispatch"):
+            keys = self._spec_verify_keys(decode_mask, T, base)
+            toks_bt, self.cache = self._verify(
+                self.params, jnp.asarray(inputs), cache, keys,
+                jnp.float32(self.temperature))
+        with span("serve.token_wait"):
+            toks = np.asarray(toks_bt, np.int32)
         self.stats["spec_rounds"] += 1
         self.stats["decode_steps"] += 1
+        self.stats["decode_rows"] += len(slots)
         # per draft token: one draft decode + one sampler dispatch
         self.stats["decode_device_programs"] += 2 * (T - 1) + 1
-        for s in slots:
-            req = self.slot_req[s]
-            # accept the longest prefix whose drafts matched the verified
-            # targets: t_j is trustworthy iff inputs[1..j] == t[0..j-1]
-            n_acc = 1
-            while n_acc < T and inputs[s, n_acc] == toks[s, n_acc - 1]:
-                n_acc += 1
-            self.stats["spec_draft_tokens"] += T - 1
-            self.stats["spec_accepted_tokens"] += n_acc - 1
-            commit = []
-            for j in range(min(n_acc, int(self.slot_remaining[s]))):
-                t = int(toks[s, j])
-                commit.append(t)
-                if req.eos_id is not None and t == req.eos_id:
-                    break
-            c = len(commit)
-            req.out_tokens.extend(commit)
-            if not self.greedy:
-                self._slot_sampled[s] = base[s] + c
-            self.lengths[s] += c
-            self.next_token[s] = commit[-1]
-            self.slot_remaining[s] -= c
-            self.stats["spec_committed_tokens"] += c
-            if self.slot_remaining[s] <= 0 or (
-                    req.eos_id is not None and commit[-1] == req.eos_id):
-                self._retire(s)
+        with span("serve.retire"):
+            for s in slots:
+                self._spec_commit(s, inputs, toks, T, base[s])
+
+    def _spec_commit(self, s: int, inputs, toks, T: int, base: int):
+        """Commit slot s's accepted tokens of a verified round."""
+        req = self.slot_req[s]
+        # accept the longest prefix whose drafts matched the verified
+        # targets: t_j is trustworthy iff inputs[1..j] == t[0..j-1]
+        n_acc = 1
+        while n_acc < T and inputs[s, n_acc] == toks[s, n_acc - 1]:
+            n_acc += 1
+        self.stats["spec_draft_tokens"] += T - 1
+        self.stats["spec_accepted_tokens"] += n_acc - 1
+        commit = []
+        for j in range(min(n_acc, int(self.slot_remaining[s]))):
+            t = int(toks[s, j])
+            commit.append(t)
+            if req.eos_id is not None and t == req.eos_id:
+                break
+        c = len(commit)
+        req.out_tokens.extend(commit)
+        if not self.greedy:
+            self._slot_sampled[s] = base + c
+        self.lengths[s] += c
+        self.next_token[s] = commit[-1]
+        self.slot_remaining[s] -= c
+        self.stats["spec_committed_tokens"] += c
+        if self.slot_remaining[s] <= 0 or (
+                req.eos_id is not None and commit[-1] == req.eos_id):
+            self._retire(s)
 
     def run(self, max_iters: int = 10_000):
         it = 0

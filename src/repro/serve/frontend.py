@@ -26,7 +26,14 @@ running batch at step granularity, never between prompt boundaries):
     cannot thrash: the victim sorts behind the request that displaced it.
   * **latency accounting**: time-to-first-token and inter-token-latency
     histograms per request, surfaced through `execution_summary()` next
-    to the engine's own datapath counters.
+    to the engine's own counters (engine.py's module docstring lists
+    them): its queue wait (`queue_wait_mean_ms`, (re)queueing ->
+    admission) and `admit_to_first_token_mean_ms` split the front end's
+    TTFT into its two terms, and `prefill_row_use` / `decode_slot_use`
+    say how much of each fixed-shape program carried live requests.
+  * **spans**: while a profiler collects, each loop iteration opens
+    `serve.frontend.expire`, `serve.frontend.preempt`, the engine's
+    `serve.step` and `serve.frontend.pump` (serve/tracing.py).
 
 The loop yields control (`await asyncio.sleep(0)`) after every engine
 step, so client coroutines interleave submissions with serving on one
@@ -43,6 +50,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from .engine import Request, ServingEngine, _FREE
+from .tracing import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -268,11 +276,13 @@ class AsyncServingFrontend:
         submitters (asyncio.gather)."""
         it = 0
         while any(t.state == "pending" for t in self._tickets.values()):
-            now = self._clock()
-            self._expire_queued(now)
-            self._maybe_preempt()
+            with span("serve.frontend.expire"):
+                self._expire_queued(self._clock())
+            with span("serve.frontend.preempt"):
+                self._maybe_preempt()
             self.engine.step()
-            self._pump(self._clock())
+            with span("serve.frontend.pump"):
+                self._pump(self._clock())
             it += 1
             if it >= max_iters:
                 raise RuntimeError(

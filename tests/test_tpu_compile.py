@@ -4,7 +4,10 @@ Interpret mode (every other test) accepts unaligned blocks, in-kernel
 reshapes and VMEM budgets that Mosaic refuses; these cases hand the
 kernels to the TPU compiler for a described (not attached) v5e chip.
 Widths: 4 slots, 8 query / 4 KV heads of 256, d_model 2560, d_ff 10240,
-vocabulary 262144, P(8,2) KV pages of 16 tokens over max_seq 2048.
+vocabulary 262144, P(8,2) KV pages of 16 tokens over max_seq 2048.  The
+serving kernels keep their explicit names in the compiled HLO, where the
+device trace reads them (`paged_attention`, `prefill_attention_paged`,
+`decode_sample`, the codec's `decode` and `encode`).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, so describing it while
@@ -12,6 +15,7 @@ pytest collects would break the other workers.
 """
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +55,15 @@ def _compile(fn, one_chip, *shapes, **kw_shapes):
     return jax.jit(fn).lower(*args, **kwargs).compile()
 
 
+KERNEL = re.compile(r"%([A-Za-z_][\w\-]*?)(?:\.\d+)? = [^\n]*"
+                    r'custom_call_target="tpu_custom_call"')
+
+
+def _kernels(compiled) -> set:
+    """Names of the Pallas kernels in a compiled program's HLO text."""
+    return set(KERNEL.findall(compiled.as_text()))
+
+
 def _pages():
     return ((NP, PS, KVD), jnp.int8)
 
@@ -66,7 +79,8 @@ CODEC = {
 @pytest.mark.parametrize("case", sorted(CODEC))
 def test_codec_compiles(case, one_chip):
     fn, dt = CODEC[case]
-    _compile(fn, one_chip, ((D, F), dt))
+    compiled = _compile(fn, one_chip, ((D, F), dt))
+    assert _kernels(compiled) == {case.split("_")[0]}
 
 
 def test_posit_matmul_compiles(one_chip):
@@ -91,9 +105,10 @@ def test_paged_attention_compiles(case, one_chip):
     def fn(q, kp, vp, bt, ln, win, ok):
         return pa.paged_attention(q, kp, vp, bt, ln, win, fmt_kv=P8_2,
                                   page_ok=ok, partials=partials)
-    _compile(fn, one_chip, (q_shape, jnp.float32), _pages(), _pages(),
-             ((B, M), jnp.int32), ((B,), jnp.int32), ((1,), jnp.int32),
-             ((B, M), jnp.int32))
+    compiled = _compile(fn, one_chip, (q_shape, jnp.float32), _pages(),
+                        _pages(), ((B, M), jnp.int32), ((B,), jnp.int32),
+                        ((1,), jnp.int32), ((B, M), jnp.int32))
+    assert _kernels(compiled) == {"paged_attention"}
 
 
 # (chunk bucket, history staged from an all-gathered global pool)
@@ -119,7 +134,8 @@ def test_prefill_attention_compiles(case, one_chip):
     if global_hist:
         shapes += [_pages(), _pages(), ((B, M), jnp.int32),
                    ((B, M), jnp.int32)]
-    _compile(fn, one_chip, *shapes)
+    compiled = _compile(fn, one_chip, *shapes)
+    assert _kernels(compiled) == {"prefill_attention_paged"}
 
 
 @pytest.mark.parametrize("greedy", [True, False], ids=["greedy", "top_k"])
@@ -136,8 +152,9 @@ def test_decode_sample_compiles_with_tpu_v_block(greedy, one_chip):
                                 jnp.float32(0.7), plan="fused", fmt_w=P16_2,
                                 transpose=True, greedy=greedy,
                                 top_k=0 if greedy else 8)
-    _compile(fn, one_chip, ((B, D), jnp.bfloat16), ((V, D), jnp.int16),
-             ((B, V), jnp.float32))
+    compiled = _compile(fn, one_chip, ((B, D), jnp.bfloat16),
+                        ((V, D), jnp.int16), ((B, V), jnp.float32))
+    assert _kernels(compiled) == {"decode_sample"}
 
 
 # published heads whose vocabulary no power-of-two tile divides: the
@@ -156,5 +173,6 @@ def test_decode_sample_compiles_masked_last_tile(case, one_chip):
         return pa.decode_sample(x, w, noise, jnp.float32(0.7), plan="fused",
                                 fmt_w=P16_2, transpose=transpose, top_k=8)
     w_shape = (V_, D_) if transpose else (D_, V_)
-    _compile(fn, one_chip, ((B, D_), jnp.bfloat16), (w_shape, jnp.int16),
-             ((B, V_), jnp.float32))
+    compiled = _compile(fn, one_chip, ((B, D_), jnp.bfloat16),
+                        (w_shape, jnp.int16), ((B, V_), jnp.float32))
+    assert _kernels(compiled) == {"decode_sample"}
