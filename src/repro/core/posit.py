@@ -34,8 +34,10 @@ def bit_length32(x):
     """Vectorized bit_length for non-negative int32/uint32 (0 -> 0).
 
     Select-chain binary search — only shifts/compares, so it lowers inside
-    Pallas TPU kernels (unlike lax.clz) and is used by both the codec and
-    the PDPU normalizer."""
+    Pallas TPU kernels (unlike lax.clz) for any 31-bit input.  It serves
+    `decode_unpacked` (and through it the bit-exact PDPU datapath) and the
+    PDPU normalizer; `decode` needs no bit length this wide and finds its
+    regime with the f32 convert instead."""
     v = x.astype(_U32)
     out = jnp.zeros(v.shape, _I32)
     for s in (16, 8, 4, 2, 1):
@@ -92,19 +94,32 @@ def decode_unpacked(codes, fmt: PositFormat):
 
 
 def decode(codes, fmt: PositFormat, dtype=jnp.float32):
-    """codes -> float values. Exact for n <= 16 into f32 (NaR -> nan).
+    """codes (low n bits of any int container) -> float values (NaR -> nan,
+    zero -> +0.0).  Bitwise equal to `posit_np.decode_np` on every code.
 
-    The f32 is assembled bit-by-bit (|scale| <= 120 keeps the exponent in
-    the normal range), so this lowers inside Pallas TPU kernels."""
-    is_zero, is_nar, sign, scale, frac = decode_unpacked(codes, fmt)
-    fb = fmt.frac_bits
-    # value = (-1)^sign * 1.mant * 2**scale, mant = low fb bits of frac
-    exp_f = jnp.where(is_zero | is_nar, 0, scale + 127)
-    mant23 = (frac & ((1 << fb) - 1)) << (23 - fb)
-    bits = (sign << 31) | (exp_f << 23) | mant23
-    val = jax.lax.bitcast_convert_type(bits.astype(_I32), jnp.float32)
-    val = jnp.where(is_zero, 0.0, val)
-    val = jnp.where(is_nar, jnp.nan, val)
+    The f32 word is assembled directly from the code, so this lowers inside
+    Pallas TPU kernels, the body of every in-kernel decode.  It is exact:
+    the n-1 bits after the sign are below 2**15 (n <= 16), so their int ->
+    f32 conversion is exact and its exponent field is their bit length,
+    which gives the regime's run; and |scale| <= max_scale <= 120 keeps
+    every value a normal f32, out of reach of a denormal flush."""
+    _check_jax_fmt(fmt)
+    n, es = fmt.n, fmt.es
+    body_mask = (1 << (n - 1)) - 1
+    xs = (codes.astype(_I32) << (32 - n)) >> (32 - n)  # sign-extended code
+    xa = jnp.abs(xs)  # NaR -> 2**(n-1), outside the body
+    r0 = (xa << (33 - n)) >> 31  # first regime bit, as 0 or -1
+    inv = xa ^ (r0 & body_mask)  # regime run -> leading zeros of the body
+    top = jax.lax.bitcast_convert_type(inv.astype(jnp.float32), _I32) >> 23
+    bl = jnp.maximum(top - 126, 0)  # bit length of inv, 0 for 0
+    # k = -run = bl - (n-1), or run - 1 = (n-2) - bl = ~(bl - (n-1)) if r0
+    k = (bl - (n - 1)) ^ r0
+    # the bits after the terminator: exponent at bit 23, fraction below
+    fld = (xa << (24 + es - bl)) & ((1 << (23 + es)) - 1)
+    bits = ((k << (23 + es)) + ((127 << 23) + fld)) | (xs & _I32(-(1 << 31)))
+    val = jax.lax.bitcast_convert_type(bits, jnp.float32)
+    val = jnp.where(xs == 0, 0.0, val)
+    val = jnp.where(xs == -(1 << (n - 1)), jnp.nan, val)
     return val.astype(dtype)
 
 

@@ -246,11 +246,11 @@ class PageAllocator:
         return recycled
 
 
-def _jit_named(name: str, fn):
+def _jit_named(name: str, fn, **jit_kw):
     """jax.jit under a stable program name: the device trace's "XLA
     Modules" line reads `jit_<name>` for it, not `jit__lambda`."""
     fn.__name__ = fn.__qualname__ = name
-    return jax.jit(fn)
+    return jax.jit(fn, **jit_kw)
 
 
 def _build_sampler(greedy: bool, top_k: int):
@@ -432,13 +432,18 @@ class ServingEngine:
                         p, t, c, cfg,
                         None if gd else api.sample_noise(keys, V),
                         temp, greedy=gd, top_k=tk))
+            # chunk programs donate the cache (arg 2) and write the page
+            # pool in place: queued back to back, as a long prompt's are,
+            # they would otherwise each hold a copy of the pool
             self._chunk = _jit_named(
                 "serve_prefill_chunk",
-                lambda p, t, c, s: api.prefill_chunk(p, t, c, s, cfg))
+                lambda p, t, c, s: api.prefill_chunk(p, t, c, s, cfg),
+                donate_argnums=2)
             self._chunk_batched = _jit_named(
                 "serve_prefill_chunk_batched",
                 lambda p, t, c, a: api.prefill_chunk_batched(p, t, c, a,
-                                                             cfg))
+                                                             cfg),
+                donate_argnums=2)
             # COW page duplication; dst/src are traced so one compile
             # covers every fork
             self._fork_fn = _jit_named(
@@ -651,12 +656,12 @@ class ServingEngine:
             lambda p, t, c, s: api.prefill_chunk(p, t, c, s, cfg,
                                                  shard=sctx),
             mesh, in_specs=(prep, rep, cspec, rep),
-            out_specs=(rep, cspec)))
+            out_specs=(rep, cspec)), donate_argnums=2)
         self._chunk_batched = _jit_named("serve_prefill_chunk_batched", sm(
             lambda p, t, c, a: api.prefill_chunk_batched(p, t, c, a, cfg,
                                                          shard=sctx),
             mesh, in_specs=(prep, rep, cspec, rep),
-            out_specs=(rep, cspec)))
+            out_specs=(rep, cspec)), donate_argnums=2)
         pool = cspec["k"]
         self._fork_fn = _jit_named("serve_fork_page", sm(
             lambda kv, d, s: fork_page(kv, d, s, shard=sctx),

@@ -476,6 +476,32 @@ def test_batched_prefill_compiles_per_bucket_not_per_slot_count(rng):
     assert got == {r.rid: r.out_tokens for r in done}
 
 
+@pytest.mark.parametrize("batched", [True, False])
+def test_chunk_programs_write_the_pool_in_place(rng, batched):
+    """A long prompt's chunk programs queue back to back; each donates the
+    page pool it is given, so the queue holds one pool and not a copy per
+    program."""
+    cfg = _tiny("command_r_35b", QuantPolicy(weights=P16_2, kv_cache=P8_2))
+    params = api.init(jax.random.key(0), cfg)
+    engine = ServingEngine(cfg, params, batch_slots=2, max_seq=32,
+                           page_size=4, prefill_buckets=(4,),
+                           batched_prefill=batched)
+    name = "_chunk_batched" if batched else "_chunk"
+    chunk, pools = getattr(engine, name), []
+
+    def logged(params, tokens, cache, *rest):
+        pools.append((cache["k"], cache["v"]))
+        return chunk(params, tokens, cache, *rest)
+    setattr(engine, name, logged)
+    engine.submit(Request(
+        rid=0, prompt=rng.integers(0, cfg.vocab_size, 12).astype(np.int32),
+        max_new_tokens=2))
+    done = engine.run()
+    assert len(done) == 1 and len(pools) == 3
+    assert all(k.is_deleted() and v.is_deleted() for k, v in pools)
+    assert not engine.cache["k"].is_deleted()
+
+
 def test_batched_prefill_auto_disabled_for_droppy_moe_capacity():
     """Routed-MoE capacity is computed over the whole batched chunk, so
     batch composition could displace active tokens when the capacity

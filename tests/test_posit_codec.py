@@ -3,6 +3,8 @@
 Three implementations (exact Fraction oracle / numpy int64 / JAX int32)
 must agree everywhere; the JAX codec is the one the kernels lower.
 """
+import jax
+import jax.extend as jex
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -14,18 +16,59 @@ from repro.core import posit as pj
 from repro.core import posit_np as pnp
 from repro.core import posit_py as ppy
 from repro.core.formats import P8_2, P13_2, P16_2, PositFormat
+from repro.kernels import posit_codec
 
 FORMATS = [P8_2, PositFormat(8, 0), PositFormat(8, 1), P13_2, P16_2,
-           PositFormat(10, 2), PositFormat(12, 3), PositFormat(6, 1)]
+           PositFormat(10, 2), PositFormat(12, 3), PositFormat(6, 1),
+           PositFormat(16, 1), PositFormat(16, 3)]
+
+
+def _bits(v):
+    """f32 values as their int32 patterns: `==` would hide a -0.0."""
+    return np.asarray(v, np.float32).view(np.int32)
 
 
 @pytest.mark.parametrize("fmt", FORMATS, ids=str)
 def test_decode_exhaustive_np_vs_jax(fmt):
     codes = np.arange(1 << fmt.n)
-    vn = pnp.decode_np(codes, fmt).astype(np.float32)
-    vj = np.asarray(pj.decode(jnp.asarray(codes, jnp.int32), fmt))
-    eq = (vn == vj) | (np.isnan(vn) & np.isnan(vj))
-    assert eq.all(), np.where(~eq)
+    vn = _bits(pnp.decode_np(codes, fmt))
+    vj = _bits(pj.decode(jnp.asarray(codes, jnp.int32), fmt))
+    assert np.array_equal(vn, vj), np.where(vn != vj)
+
+
+@pytest.mark.parametrize("fmt,store", [(P16_2, jnp.int16), (P8_2, jnp.int8)],
+                         ids=["P16_2-int16", "P8_2-int8"])
+def test_decode_kernel_exhaustive_bitwise(fmt, store):
+    """Every code through the Pallas decode kernel (interpret mode), from
+    its storage container: 65536 P(16,2) codes as [256, 256] int16, the
+    256 P(8,2) codes as int8."""
+    codes = np.arange(1 << fmt.n).reshape(-1, 256)
+    got = posit_codec.decode(jnp.asarray(codes).astype(store), fmt,
+                             interpret=True)
+    assert np.array_equal(_bits(got), _bits(pnp.decode_np(codes, fmt)))
+
+
+def _primitive_count(jaxpr) -> int:
+    """Primitive equations of a jaxpr and its sub-jaxprs, broadcasts out."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        subs = [v for v in eqn.params.values()
+                if isinstance(v, (jex.core.Jaxpr, jex.core.ClosedJaxpr))]
+        if subs:
+            n += sum(_primitive_count(getattr(j, "jaxpr", j)) for j in subs)
+        elif eqn.primitive.name != "broadcast_in_dim":
+            n += 1
+    return n
+
+
+@pytest.mark.parametrize("fmt", [P16_2, P8_2], ids=str)
+def test_decode_op_count(fmt):
+    """The decode every kernel inlines stays a short straight line: the
+    regime's run comes from the f32 convert, not from a select chain
+    (`bit_length32` alone traces to 39 ops)."""
+    jaxpr = jax.make_jaxpr(lambda c: pj.decode(c, fmt))(
+        jnp.zeros((8, 128), jnp.int32))
+    assert _primitive_count(jaxpr.jaxpr) <= 40
 
 
 @pytest.mark.parametrize("fmt", [P8_2, PositFormat(8, 0), PositFormat(6, 1)], ids=str)
